@@ -41,6 +41,8 @@ SUITES = [
 def main():
     from repro.analysis.guards import assert_x64_disabled
     assert_x64_disabled(where="benchmarks/run.py")
+    from repro.common import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--smoke", action="store_true",

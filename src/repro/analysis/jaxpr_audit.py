@@ -14,13 +14,15 @@ import warnings
 from typing import Iterator, List, Optional, Tuple
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # Host-callback primitives that must never appear inside the donated chunk
 # body: each one forces a device->host sync per scan iteration, destroying
 # exactly the dispatch win run_compiled exists for (and breaking donation
 # on some backends).
 CALLBACK_PRIMITIVES = frozenset(
-    {"pure_callback", "io_callback", "debug_callback", "callback"})
+    {"pure_callback", "io_callback", "debug_callback", "debug_print",
+     "callback"})
 
 # 8-byte dtypes that must never leak into the compiled path (the repo's
 # numerics contract is float32 end to end; fp64 doubles every wire payload
@@ -34,15 +36,15 @@ def _subjaxprs(params) -> Iterator:
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for item in vs:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 yield item
 
 
 def iter_eqns(jaxpr) -> Iterator:
     """Depth-first over every equation, sub-jaxprs included."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn

@@ -31,7 +31,8 @@ RULES = {
     "W003": "a method's declared wire_channels must match the channels its "
             "traced round step actually crosses",
     "C001": "no host callbacks (pure_callback / io_callback / "
-            "debug_callback) inside the donated lax.scan chunk body",
+            "debug_callback / debug_print) inside the donated lax.scan "
+            "chunk body",
     "C002": "no float64 values anywhere in the compiled chunk jaxpr",
     "D001": "donation must hold: every donated chunk-carry leaf is aliased "
             "into an output buffer (no silent copy)",

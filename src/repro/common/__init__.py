@@ -1,5 +1,7 @@
-"""Shared utilities: dtypes, PRNG plumbing, pytree helpers."""
+"""Shared utilities: dtypes, PRNG plumbing, pytree helpers, compile cache."""
 from __future__ import annotations
+
+import os
 
 import jax
 import jax.numpy as jnp
@@ -66,3 +68,19 @@ def tree_allfinite(tree) -> jnp.ndarray:
 def global_norm(tree) -> jnp.ndarray:
     leaves = jax.tree_util.tree_leaves(tree)
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    the cache (JAX reads it itself) and nothing else is configured;
+    otherwise the cache lives at ``<repo>/.jax_cache``.  Entry points call
+    this at start-up, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    # a fixed path inside the checkout: JAX keys cache entries on it
+    path = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
+                                        os.pardir, os.pardir, ".jax_cache"))
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -1,9 +1,12 @@
 import os
+# Importing this module rewrites XLA_FLAGS for the whole process (512
+# placeholder CPU devices).  Code that runs on a chip (the trainer, the
+# benchmarks, chip_smoke.py) must never import it, even indirectly.
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
 
-The two lines above run before ANY other import (jax locks the device count
+The statements above run before ANY other import (jax locks the device count
 on first init): this process sees 512 placeholder CPU devices so
 ``make_production_mesh`` can build the production meshes.  Nothing is
 allocated — inputs are ShapeDtypeStructs, params come from ``eval_shape``.

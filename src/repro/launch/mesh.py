@@ -8,6 +8,16 @@ chips (TPU v5e pod slice); multi-pod = (2, 16, 16) over ("pod", "data",
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the rules in ``repro.sharding``
+    are partitioner hints, while JAX's default Explicit axes would make
+    every sharding part of the array's type (and refuse, e.g., a vmap
+    over a client axis sharded differently from its peers)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None):
@@ -23,14 +33,14 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
     devs = jax.devices()
     if len(devs) > need:        # 512 placeholder devices, single-pod mesh
         devs = devs[:need]
-    return jax.make_mesh(shape, axes, devices=devs)
+    return _mesh(shape, axes, devices=devs)
 
 
 def make_host_mesh(model: int = 2, data: int = 2, pod: int = 0):
     """Small mesh over however many (host) devices exist — for tests."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline analysis (per chip)

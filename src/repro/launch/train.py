@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +45,7 @@ from repro.population import Population, VirtualPool
 from repro.sched import COHORT_SAMPLERS, available_policies, \
     scheduler_from_flags
 from repro.transport import available_codecs
-from repro.common import bytes_of, count_params
+from repro.common import bytes_of, count_params, enable_compile_cache
 from repro.data import FederatedBatcher, partition_dirichlet, partition_iid, \
     synthetic_lm
 from repro.launch.mesh import make_host_mesh, make_production_mesh
@@ -117,9 +118,15 @@ class LMPool:
         return self.inner.round_indices(ids, rnd)
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return the
+    run: ``trainer``, ``population`` (None without ``--population``),
+    ``data`` (the batcher or population pool), ``state``, ``history``,
+    ``meter`` and ``telemetry`` (None unless a telemetry output was asked
+    for)."""
     from repro.analysis.guards import assert_x64_disabled
     assert_x64_disabled(where="launch/train.py")
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--rounds", type=int, default=50)
@@ -204,7 +211,7 @@ def main():
                     help="bracket training with jax.profiler.start_trace/"
                          "stop_trace writing a TensorBoard/Perfetto XLA "
                          "profile under PATH")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.size == "reduced":
@@ -388,6 +395,10 @@ def main():
             tele.export_prometheus(args.prom)
             print(f"telemetry: {len(tele.counters) + len(tele.gauges)} "
                   f"series -> {args.prom}")
+    return SimpleNamespace(trainer=trainer, population=pop,
+                           data=batcher if pop is None else pool_data,
+                           state=state, history=history, meter=meter,
+                           telemetry=tele)
 
 
 if __name__ == "__main__":

@@ -108,6 +108,7 @@ class Population:
         self._unit = self.trainer.method.unit_batches(self.fsl)
         self._agg_every = self.fsl.resolved_agg_every
         self._state = None
+        self._mesh_pool = None
         self._default: Dict[str, Any] = {}
         self._cache: Dict[int, Dict[str, Any]] = {}
         self._cohorts: Dict[int, np.ndarray] = {}
@@ -197,6 +198,19 @@ class Population:
         self._state = jax.tree_util.tree_map(
             lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
             self._state, specs)
+
+    def device_pool(self):
+        """The data backend's sample pool, replicated over the mesh when
+        there is one: every device gathers its clients' batches locally,
+        and the pool is not left on the first device alone."""
+        pool = self.data.device_pool()
+        if self.mesh is None:
+            return pool
+        if self._mesh_pool is None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._mesh_pool = jax.device_put(
+                pool, NamedSharding(self.mesh, PartitionSpec()))
+        return self._mesh_pool
 
     # -- lifecycle ----------------------------------------------------------
     def init(self, seed: int = 0):
@@ -429,7 +443,7 @@ class Population:
         t = self.trainer
         state = self._state
         rnd0 = t.method.batches_trained(self.fsl, state) // self.fsl.h
-        pool = self.data.device_pool()
+        pool = self.device_pool()
         history: List[dict] = []
         profile = None
         C = self.cohort_size

@@ -107,7 +107,7 @@ def test_seeded_c001_host_callback_in_chunk(monkeypatch, bundle):
     _patch_method(monkeypatch, "cse_fsl", CallbackChunk())
     vs, _ = audit_chunk("cse_fsl", bundle=bundle)
     c = [v for v in vs if v.rule == "C001"]
-    assert c and "debug_callback" in c[0].message
+    assert c and "debug_print" in c[0].message
     assert "method=cse_fsl" in c[0].combo
 
 
@@ -132,7 +132,7 @@ def test_kernel_audit_surface_is_clean():
 
 def test_seeded_c002_float64_leak():
     from repro.analysis.contracts import _hygiene
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: jnp.sum(x.astype(jnp.float64)))(
                 jax.ShapeDtypeStruct((4,), jnp.float32))
@@ -316,7 +316,7 @@ def test_seeded_t001_callback_in_telemetry_chunk(bundle):
 
     vs = audit_telemetry(bundle=bundle, telemetry_chunk=evil_chunk)
     assert _rules(vs) == ["T001", "T001"]
-    assert any("debug_callback" in v.message for v in vs)
+    assert any("debug_print" in v.message for v in vs)
     assert any("changed the compiled program" in v.message for v in vs)
     assert all("program=telemetry" in v.combo for v in vs)
 

@@ -176,3 +176,29 @@ def test_swa_matches_dense_attention_when_window_covers_seq():
     want = attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# per-tile quantize: grid over groups of row tiles
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("fmt", ["int8", "fp8"])
+@pytest.mark.parametrize("shape", [(1, 130), (5, 1024), (64, 256),
+                                   (70, 300), (131, 129)])
+def test_quantize_caller_bits_bitwise_on_ragged_shapes(fmt, shape):
+    """The kernel visits row tiles eight at a time and writes their scales
+    into one resident block per group.  On a single row tile, on exactly
+    one group, and on ragged multi-group shapes the caller-bits path stays
+    bitwise equal to the oracle, and the scales keep their
+    [ceil(R/8), ceil(C/128)] wire shape."""
+    from repro.kernels import quantize as qk
+    x = jax.random.normal(jax.random.PRNGKey(4), shape, jnp.float32) * 3
+    x = x.at[0, 0].set(50.0)                 # one outlier tile
+    bits = jax.random.bits(jax.random.PRNGKey(5), shape, jnp.uint32)
+    qa, sa = qk.quantize_2d(x, bits, fmt=fmt)
+    qb, sb = ref.quantize_2d(x, bits, fmt=fmt)
+    assert sa.shape == (-(-shape[0] // 8), -(-shape[1] // 128))
+    np.testing.assert_array_equal(np.asarray(sa), np.asarray(sb))
+    np.testing.assert_array_equal(np.asarray(qa, np.float32),
+                                  np.asarray(qb, np.float32))
