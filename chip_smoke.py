@@ -65,7 +65,10 @@ def run_train(name, extra, size="full"):
                if k.endswith("loss") and not math.isfinite(v)}
         if bad:
             raise AssertionError(f"[{name}] non-finite losses {bad} in {row}")
-    execs = [s.dur for s in res.telemetry.spans if s.name == "chunk/execute"]
+    spans = res.telemetry.spans
+    execs = [d.dur + f.dur for d, f in zip(
+        [s for s in spans if s.name == "chunk/dispatch"],
+        [s for s in spans if s.name == "chunk/fetch"])]
     print(f"[{name}] losses: "
           + json.dumps([{k: v for k, v in r.items() if k.endswith("loss")}
                         for r in rows]))

@@ -33,6 +33,7 @@ from jax import lax
 from repro.configs.base import FSLConfig
 from repro.core.accounting import CostModel
 from repro.core.bundle import SplitModelBundle
+from repro.core.stages import CLIENT_STEP, FEDAVG, SERVER_CONSUME
 
 # ---------------------------------------------------------------------------
 # Declarative communication / storage profile (paper Table II per method)
@@ -210,25 +211,28 @@ def assemble_round_step(hooks: AsyncHooks, fsl: FSLConfig,
 
     def unit_step(state, ubatch, lr):
         cstack = {k: state[k] for k in stacked}
-        cstack, uploads, pendings, cmetrics = jax.vmap(
-            lambda cs, b: hooks.client_compute(cs, b, lr))(cstack, ubatch)
+        with jax.named_scope(CLIENT_STEP):
+            cstack, uploads, pendings, cmetrics = jax.vmap(
+                lambda cs, b: hooks.client_compute(cs, b, lr))(cstack,
+                                                               ubatch)
         if code_up:
             uploads = jax.vmap(tp.code_uplink)(uploads,
                                                _client_keys(state, "uplink"))
-        if shared:
-            def consume(sstate, up):
-                if server_constraint is not None:
-                    up = jax.tree_util.tree_map(server_constraint, up)
-                sstate, reply, m = hooks.server_consume(sstate, up, lr)
-                return sstate, (reply, m)
+        with jax.named_scope(SERVER_CONSUME):
+            if shared:
+                def consume(sstate, up):
+                    if server_constraint is not None:
+                        up = jax.tree_util.tree_map(server_constraint, up)
+                    sstate, reply, m = hooks.server_consume(sstate, up, lr)
+                    return sstate, (reply, m)
 
-            sstate, (replies, smetrics) = lax.scan(
-                consume, state[skey], uploads, unroll=unroll)
-        else:
-            sstates, replies, smetrics = jax.vmap(
-                lambda s, up: hooks.server_consume(s, up, lr))(
-                    cstack[skey], uploads)
-            cstack = {**cstack, skey: sstates}
+                sstate, (replies, smetrics) = lax.scan(
+                    consume, state[skey], uploads, unroll=unroll)
+            else:
+                sstates, replies, smetrics = jax.vmap(
+                    lambda s, up: hooks.server_consume(s, up, lr))(
+                        cstack[skey], uploads)
+                cstack = {**cstack, skey: sstates}
         if blocking:
             if code_down:
                 replies = jax.vmap(tp.code_downlink)(
@@ -259,6 +263,14 @@ def assemble_round_step(hooks: AsyncHooks, fsl: FSLConfig,
 # ---------------------------------------------------------------------------
 # Compiled multi-round execution: R rounds fused into one scanned program
 # ---------------------------------------------------------------------------
+
+
+def _in_scope(name: str, fn):
+    """``fn`` traced under ``jax.named_scope(name)``."""
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
 
 
 def make_chunk_step(round_step, aggregate, fsl: FSLConfig,
@@ -318,6 +330,9 @@ def make_chunk_step(round_step, aggregate, fsl: FSLConfig,
     (only the tiny index plan crosses per chunk).
     """
     agg_every = fsl.resolved_agg_every
+    aggregate = _in_scope(FEDAVG, aggregate)
+    if masked_aggregate is not None:
+        masked_aggregate = _in_scope(FEDAVG, masked_aggregate)
 
     def advance(st, batch, lr):
         """One round + the in-carry C-batch threshold crossing."""

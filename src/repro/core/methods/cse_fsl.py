@@ -43,6 +43,7 @@ from jax import lax
 
 from repro.configs.base import FSLConfig
 from repro.core.bundle import SplitModelBundle
+from repro.core.stages import CLIENT_STEP, CLIENT_UPDATE, SERVER_CONSUME
 from repro.core.methods.base import (AsyncHooks, FSLMethod,
                                      assemble_round_step, client_mean,
                                      fedavg, register, stack_clients)
@@ -90,7 +91,8 @@ def make_client_round(bundle: SplitModelBundle, fsl: FSLConfig):
                 lambda pr: bundle.client_loss(pr["params"], pr["aux"],
                                               binputs, blabels),
                 has_aux=True)(params)
-            new_params, new_opt = opt_update(grads, opt, params, lr)
+            with jax.named_scope(CLIENT_UPDATE):
+                new_params, new_opt = opt_update(grads, opt, params, lr)
             return (new_params, new_opt), loss
 
         (params, opt), losses = lax.scan(
@@ -126,9 +128,10 @@ def _make_batched_round_step(bundle: SplitModelBundle, fsl: FSLConfig,
 
     def round_step(state, batch, lr):
         inputs, labels = batch
-        cstates, smashed, slabels, closs = jax.vmap(
-            client_round, in_axes=(0, 0, None))(state["clients"],
-                                                (inputs, labels), lr)
+        with jax.named_scope(CLIENT_STEP):
+            cstates, smashed, slabels, closs = jax.vmap(
+                client_round, in_axes=(0, 0, None))(state["clients"],
+                                                    (inputs, labels), lr)
         if not tp.uplink.is_identity:
             base = tp.unit_key(state["round"])
             keys = jax.vmap(jax.random.fold_in, (None, 0))(base,
@@ -138,10 +141,11 @@ def _make_batched_round_step(bundle: SplitModelBundle, fsl: FSLConfig,
         smashed = lax.stop_gradient(smashed)
         merged_sm = smashed.reshape((-1,) + smashed.shape[2:])
         merged_lb = slabels.reshape((-1,) + slabels.shape[2:])
-        loss, grads = jax.value_and_grad(bundle.server_loss)(
-            state["server"]["params"], merged_sm, merged_lb)
-        params, opt = opt_update(grads, state["server"]["opt"],
-                                 state["server"]["params"], lr * n)
+        with jax.named_scope(SERVER_CONSUME):
+            loss, grads = jax.value_and_grad(bundle.server_loss)(
+                state["server"]["params"], merged_sm, merged_lb)
+            params, opt = opt_update(grads, state["server"]["opt"],
+                                     state["server"]["params"], lr * n)
         new_state = {"clients": cstates,
                      "server": {"params": params, "opt": opt},
                      "round": state["round"] + 1}

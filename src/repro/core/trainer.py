@@ -590,140 +590,150 @@ class Trainer:
         if chunk < 1:
             raise ValueError(f"chunk must be >= 1, got {chunk} "
                              "(use Trainer.run for the per-round loop)")
-        start_batches = self.method.batches_trained(self.fsl, state)
-        rnd0 = start_batches // self.fsl.h
-        n = self.fsl.num_clients
-        history = []
-        profile = None
-        done = 0
-        sched_active = not self.scheduler.is_wait_all
-        fault_active = not self.faults.is_null
-        use_masks = sched_active or fault_active
-        horizon = rnd0 + num_rounds
-        ftrace = self._plan_faults(horizon) if fault_active else None
-        fstats = self._fault_stats
-        unit_bytes = None
-        blocking = self.method.downloads_gradients
-        masks = ms_pair = part_dev = None
-        # host mirror of the in-scan participation carry — same math, so
-        # rows/meter/warnings match Trainer.run exactly
-        part = np.ones(n, bool) if use_masks else None
-        part_s = np.ones(n, bool) if (sched_active and fault_active) else None
-        dropped_updates = 0
-        pooled = (device_data and hasattr(batcher, "device_pool")
-                  and hasattr(batcher, "next_round_indices"))
-        pool = batcher.device_pool() if pooled else None
-        tele = self.telemetry
-        chunk_idx = 0
-        seen_r = set()          # chunk lengths already compiled this call
-        while done < num_rounds:
-            r = min(chunk, num_rounds - done)
-            # host spans ("chunk/build" staging vs "chunk/execute" dispatch
-            # + fetch) are observation-only wall-clock brackets; the first
-            # dispatch of each chunk length includes XLA compilation
-            # (labelled first_dispatch — use --profile-dir for the real
-            # jax.profiler compile/execute breakdown)
-            with tele.timed("chunk/build", chunk=chunk_idx, rounds=r):
-                if pooled:
-                    idx = np.stack([batcher.next_round_indices()
-                                    for _ in range(r)])      # [R, n, h, B]
-                    sample = self.pool_round_spec(pool, idx.shape[1:])
-                    batches = None
-                else:
-                    rounds = [batcher.next_round() for _ in range(r)]
-                    sample = rounds[0]
-                    batches = jax.tree_util.tree_map(_stack_rounds, *rounds)
-                if meter is not None and cost_model is not None \
-                        and profile is None:
-                    batch_size = jax.tree_util.tree_leaves(
-                        sample[1])[0].shape[2]
-                    profile = self.comm_profile(cost_model, batch_size,
-                                                batch=sample)
-                if use_masks and masks is None:
-                    masks = self._effective_masks(sample, horizon, ftrace)
-                lrs = jnp.asarray([self.lr_at(rnd0 + done + i)
-                                   for i in range(r)], jnp.float32)
-            with tele.timed("chunk/execute", chunk=chunk_idx, rounds=r,
-                            first_dispatch=r not in seen_r):
-                if use_masks:
-                    if part_dev is None:
-                        part_dev = jnp.ones(n, jnp.float32)
-                    mk = jnp.asarray(masks[rnd0 + done:rnd0 + done + r],
-                                     jnp.float32)
+        # "chunk/run" spans the whole call, so a device-idle gap anywhere
+        # in it (the resume read of the round counter included) is named
+        # by a chunk/* span on a profiler trace, not by a Python frame
+        with self.telemetry.timed("chunk/run", rounds=num_rounds):
+            start_batches = self.method.batches_trained(self.fsl, state)
+            rnd0 = start_batches // self.fsl.h
+            n = self.fsl.num_clients
+            history = []
+            profile = None
+            done = 0
+            sched_active = not self.scheduler.is_wait_all
+            fault_active = not self.faults.is_null
+            use_masks = sched_active or fault_active
+            horizon = rnd0 + num_rounds
+            ftrace = self._plan_faults(horizon) if fault_active else None
+            fstats = self._fault_stats
+            unit_bytes = None
+            blocking = self.method.downloads_gradients
+            masks = ms_pair = part_dev = None
+            # host mirror of the in-scan participation carry — same math, so
+            # rows/meter/warnings match Trainer.run exactly
+            part = np.ones(n, bool) if use_masks else None
+            part_s = np.ones(n, bool) if (sched_active and fault_active) \
+                else None
+            dropped_updates = 0
+            pooled = (device_data and hasattr(batcher, "device_pool")
+                      and hasattr(batcher, "next_round_indices"))
+            pool = batcher.device_pool() if pooled else None
+            tele = self.telemetry
+            chunk_idx = 0
+            while done < num_rounds:
+                r = min(chunk, num_rounds - done)
+                # host spans, observation-only: "chunk/build" stages the
+                # plan, "chunk/dispatch" calls the jitted chunk (a compile,
+                # counted in the span's ``compiles`` label, happens here),
+                # "chunk/fetch" waits for its metrics, "chunk/log" is the
+                # per-round host loop; each is a jax.profiler annotation
+                with tele.timed("chunk/build", chunk=chunk_idx, rounds=r):
                     if pooled:
-                        state, metrics, agg_mask, part_dev = \
-                            self.masked_pool_chunk_fn(state, pool,
-                                                      jnp.asarray(idx), lrs,
-                                                      mk, part_dev)
+                        idx = np.stack([batcher.next_round_indices()
+                                        for _ in range(r)])      # [R, n, h, B]
+                        sample = self.pool_round_spec(pool, idx.shape[1:])
+                        batches = None
                     else:
-                        state, metrics, agg_mask, part_dev = \
-                            self.masked_chunk_fn(state, batches, lrs, mk,
-                                                 part_dev)
-                elif pooled:
-                    state, metrics, agg_mask = self.pool_chunk_fn(
-                        state, pool, jnp.asarray(idx), lrs)
-                else:
-                    state, metrics, agg_mask = self.chunk_fn(state, batches,
-                                                             lrs)
-                # ONE host fetch per chunk: the stacked metrics + agg mask
-                agg_mask = np.asarray(agg_mask)
-                metrics = {k: np.asarray(v) for k, v in metrics.items()}
-            seen_r.add(r)
-            chunk_idx += 1
-            for i in range(r):
-                rnd = rnd0 + done + i
-                aggregated = bool(agg_mask[i])
-                extra = ms_bytes = wire = None
-                if use_masks:
-                    part &= masks[rnd]
-                    if part_s is not None:
-                        part_s &= self._sched_masks[rnd]
-                if fault_active and profile is not None:
-                    if unit_bytes is None:
-                        unit_bytes = profile.unit_wire_bytes(
-                            n, self._uploads_per_round())
-                    wire = accumulate_round(fstats, self.faults, ftrace,
-                                            rnd, *unit_bytes, blocking,
-                                            FRAME_BYTES)
-                if use_masks and aggregated:
-                    k = int(part.sum())
-                    if k == 0:
-                        who = (f"scheduler {self.scheduler.name!r}"
-                               if sched_active else
-                               f"fault model {self.faults.name!r}")
-                        warnings.warn(
-                            f"{who} admitted no clients at the "
-                            f"round-{rnd + 1} aggregation; FedAvg skipped "
-                            "(no-op)")
-                    dropped_updates += n - k
-                    extra = {"participants": k,
-                             "dropped_updates": dropped_updates}
-                    if fault_active:
-                        fstats.windows += 1
-                        fstats.participants.append(k)
-                        if k == 0:
-                            fstats.empty_windows += 1
-                        if part_s is not None:
-                            fstats.deadline_drops += n - int(part_s.sum())
-                            part_s[:] = True
-                        extra.update(
-                            fault_retries=fstats.retries,
-                            fault_drops=fstats.crash_drops + fstats.wire_drops)
-                    if profile is not None:
-                        if ms_pair is None:
-                            ms_pair = self._model_sync_wire_pair()
-                        recv = n if self.scheduler.refresh_dropped else k
-                        ms_bytes = 0 if k == 0 \
-                            else k * ms_pair[0] + recv * ms_pair[1]
-                    part[:] = True
-                self._log_round(
-                    rnd, rnd0, aggregated,
-                    lambda: {k: float(v[i]) for k, v in metrics.items()},
-                    profile, meter, log_every, callback, history, state,
-                    extra=extra, model_sync_bytes=ms_bytes, wire_bytes=wire,
-                    engine="compiled")
-            done += r
-        if tele.enabled:
-            tele.run_summary("compiled", comm=meter,
-                             participation=self.participation_summary())
-        return state, history
+                        rounds = [batcher.next_round() for _ in range(r)]
+                        sample = rounds[0]
+                        batches = jax.tree_util.tree_map(_stack_rounds,
+                                                         *rounds)
+                    if meter is not None and cost_model is not None \
+                            and profile is None:
+                        batch_size = jax.tree_util.tree_leaves(
+                            sample[1])[0].shape[2]
+                        profile = self.comm_profile(cost_model, batch_size,
+                                                    batch=sample)
+                    if use_masks and masks is None:
+                        masks = self._effective_masks(sample, horizon, ftrace)
+                    lrs = jnp.asarray([self.lr_at(rnd0 + done + i)
+                                       for i in range(r)], jnp.float32)
+                with tele.timed("chunk/dispatch", chunk=chunk_idx, rounds=r):
+                    if use_masks:
+                        if part_dev is None:
+                            part_dev = jnp.ones(n, jnp.float32)
+                        mk = jnp.asarray(masks[rnd0 + done:rnd0 + done + r],
+                                         jnp.float32)
+                        if pooled:
+                            state, metrics, agg_mask, part_dev = \
+                                self.masked_pool_chunk_fn(
+                                    state, pool, jnp.asarray(idx), lrs, mk,
+                                    part_dev)
+                        else:
+                            state, metrics, agg_mask, part_dev = \
+                                self.masked_chunk_fn(state, batches, lrs, mk,
+                                                     part_dev)
+                    elif pooled:
+                        state, metrics, agg_mask = self.pool_chunk_fn(
+                            state, pool, jnp.asarray(idx), lrs)
+                    else:
+                        state, metrics, agg_mask = self.chunk_fn(
+                            state, batches, lrs)
+                with tele.timed("chunk/fetch", chunk=chunk_idx, rounds=r):
+                    # ONE host fetch per chunk: the stacked metrics + agg mask
+                    agg_mask = np.asarray(agg_mask)
+                    metrics = {k: np.asarray(v) for k, v in metrics.items()}
+                with tele.timed("chunk/log", chunk=chunk_idx, rounds=r):
+                    for i in range(r):
+                        rnd = rnd0 + done + i
+                        aggregated = bool(agg_mask[i])
+                        extra = ms_bytes = wire = None
+                        if use_masks:
+                            part &= masks[rnd]
+                            if part_s is not None:
+                                part_s &= self._sched_masks[rnd]
+                        if fault_active and profile is not None:
+                            if unit_bytes is None:
+                                unit_bytes = profile.unit_wire_bytes(
+                                    n, self._uploads_per_round())
+                            wire = accumulate_round(
+                                fstats, self.faults, ftrace, rnd,
+                                *unit_bytes, blocking, FRAME_BYTES)
+                        if use_masks and aggregated:
+                            k = int(part.sum())
+                            if k == 0:
+                                who = (f"scheduler {self.scheduler.name!r}"
+                                       if sched_active else
+                                       f"fault model {self.faults.name!r}")
+                                warnings.warn(
+                                    f"{who} admitted no clients at the "
+                                    f"round-{rnd + 1} aggregation; FedAvg "
+                                    "skipped (no-op)")
+                            dropped_updates += n - k
+                            extra = {"participants": k,
+                                     "dropped_updates": dropped_updates}
+                            if fault_active:
+                                fstats.windows += 1
+                                fstats.participants.append(k)
+                                if k == 0:
+                                    fstats.empty_windows += 1
+                                if part_s is not None:
+                                    fstats.deadline_drops += \
+                                        n - int(part_s.sum())
+                                    part_s[:] = True
+                                extra.update(
+                                    fault_retries=fstats.retries,
+                                    fault_drops=(fstats.crash_drops
+                                                 + fstats.wire_drops))
+                            if profile is not None:
+                                if ms_pair is None:
+                                    ms_pair = self._model_sync_wire_pair()
+                                recv = n if self.scheduler.refresh_dropped \
+                                    else k
+                                ms_bytes = 0 if k == 0 \
+                                    else k * ms_pair[0] + recv * ms_pair[1]
+                            part[:] = True
+                        self._log_round(
+                            rnd, rnd0, aggregated,
+                            lambda: {k: float(v[i])
+                                     for k, v in metrics.items()},
+                            profile, meter, log_every, callback, history,
+                            state,
+                            extra=extra, model_sync_bytes=ms_bytes,
+                            wire_bytes=wire, engine="compiled")
+                chunk_idx += 1
+                done += r
+            if tele.enabled:
+                tele.run_summary("compiled", comm=meter,
+                                 participation=self.participation_summary())
+            return state, history

@@ -24,6 +24,7 @@ from jax import lax
 
 from repro.common import dtype_of
 from repro.configs.base import ModelConfig
+from repro.core.stages import AUX_CE
 from repro.models import layers as L
 from repro.models.blocks import (BLOCKS, Ctx, attn_cache_spec, block_cache_spec,
                                  block_kind, dense_apply, dense_init)
@@ -312,10 +313,11 @@ def server_logits_fn(cfg: ModelConfig, sp) -> Callable:
 def client_loss(cfg: ModelConfig, cp, ap, inputs, labels, ctx: Ctx):
     """Local loss through the auxiliary head (Eq. 5). Returns (loss, smashed)."""
     smashed, moe_aux, _ = client_forward(cfg, cp, inputs, ctx)
-    pre = L.rmsnorm(smashed, ap["ln"])
-    if "down" in ap:
-        pre = pre @ ap["down"]
-    loss = head_ce(cfg, pre, ap["up"], labels)
+    with jax.named_scope(AUX_CE):
+        pre = L.rmsnorm(smashed, ap["ln"])
+        if "down" in ap:
+            pre = pre @ ap["down"]
+        loss = head_ce(cfg, pre, ap["up"], labels)
     return loss + MOE_AUX_COEF * moe_aux, smashed
 
 

@@ -85,7 +85,8 @@ class Population:
     faults: Optional[Any] = None
     # observability (repro.telemetry): forwarded to the inner Trainer;
     # the cohort engine emits per-round records under engine="population"
-    # plus chunk build/execute host spans.  Observation-only (rule T001).
+    # plus chunk build/dispatch/fetch/log host spans.  Observation-only
+    # (rule T001).
     telemetry: Optional[Any] = None
 
     def __post_init__(self):
@@ -442,134 +443,142 @@ class Population:
         from repro.faults import FRAME_BYTES, accumulate_round
         t = self.trainer
         state = self._state
-        rnd0 = t.method.batches_trained(self.fsl, state) // self.fsl.h
-        pool = self.device_pool()
-        history: List[dict] = []
-        profile = None
-        C = self.cohort_size
-        fault_active = not self.faults.is_null
-        blocking = t.method.downloads_gradients
-        ftrace = fstats = surv = part = part_dev = None
-        unit_bytes = ms_pair = None
-        dropped_updates = 0
-        if fault_active:
-            ftrace = t._plan_faults(rnd0 + num_rounds)
-            fstats = t._fault_stats
-            surv = ftrace.survives(blocking)
-            part = np.ones(C, bool)
-            part_dev = jnp.ones(C, jnp.float32)
-        done = 0
-        while done < num_rounds:
-            r0 = rnd0 + done
-            w0 = self.window_of(r0)
-            if w0 != self._window:
-                if fault_active:
-                    state = self._close_window(state)
-                self._state = state
-                self._advance_window(w0)
-                state = self._state
-            seg = min(chunk, num_rounds - done)
-            if not self.refresh or fault_active:
-                # faults also cut segments at window boundaries, so an
-                # empty window can be repaired host-side before the next
-                # cohort trains on its rows
-                s = 1
-                while s < seg and self.window_of(r0 + s) == w0:
-                    s += 1
-                seg = s
-            with self.telemetry.timed("chunk/build", window=w0, rounds=seg):
-                plans = []
-                for i in range(seg):
-                    w = self.window_of(r0 + i)
-                    ids = self.cohort_for(w)
-                    plans.append(self.data.round_indices(ids, r0 + i))
-                sample = t.pool_round_spec(pool, plans[0].shape)
-                if self._payload_bytes is None:
-                    up_spec, reply_spec = t.method.payload_specs(
-                        self.bundle, self.fsl, sample)
-                    self._payload_bytes = (
-                        t.transport.uplink_payload_bytes(up_spec),
-                        t.transport.downlink_payload_bytes(reply_spec)
-                        if reply_spec is not None else 0)
-                for i in range(seg):
-                    w = self.window_of(r0 + i)
-                    self._record_window(w, self.cohort_for(w), r0 + i)
-                if meter is not None and cost_model is not None \
-                        and profile is None:
-                    batch_size = jax.tree_util.tree_leaves(
-                        sample[1])[0].shape[2]
-                    profile = t.comm_profile(cost_model, batch_size,
-                                             batch=sample)
-                idx = jnp.asarray(np.stack(plans))
-                lrs = jnp.asarray([t.lr_at(r0 + i) for i in range(seg)],
-                                  jnp.float32)
-            with self.telemetry.timed("chunk/execute", window=w0,
-                                      rounds=seg):
-                if fault_active:
-                    mk = jnp.asarray(surv[r0:r0 + seg], jnp.float32)
-                    state, metrics, agg_mask, part_dev = \
-                        t.masked_pool_chunk_fn(state, pool, idx, lrs, mk,
-                                               part_dev)
-                else:
-                    state, metrics, agg_mask = t.pool_chunk_fn(state, pool,
-                                                               idx, lrs)
-                agg_mask = np.asarray(agg_mask)
-                metrics = {k: np.asarray(v) for k, v in metrics.items()}
-            for i in range(seg):
-                rnd = r0 + i
-                aggregated = bool(agg_mask[i])
-                extra = ms_bytes = wire = None
-                if fault_active:
-                    part &= surv[rnd]
-                    if profile is not None:
-                        if unit_bytes is None:
-                            unit_bytes = profile.unit_wire_bytes(
-                                C, t._uploads_per_round())
-                        wire = accumulate_round(fstats, self.faults, ftrace,
-                                                rnd, *unit_bytes, blocking,
-                                                FRAME_BYTES)
-                    if aggregated:
-                        k = int(part.sum())
-                        if k == 0:
-                            self._window_empty = True
-                            warnings.warn(
-                                f"fault model {self.faults.name!r} admitted "
-                                f"no clients at the round-{rnd + 1} "
-                                "aggregation; FedAvg skipped (no-op)")
-                        dropped_updates += C - k
-                        fstats.windows += 1
-                        fstats.participants.append(k)
-                        if k == 0:
-                            fstats.empty_windows += 1
-                        extra = {"participants": k,
-                                 "dropped_updates": dropped_updates,
-                                 "fault_retries": fstats.retries,
-                                 "fault_drops": (fstats.crash_drops
-                                                 + fstats.wire_drops)}
-                        if profile is not None:
-                            if ms_pair is None:
-                                ms_pair = t._model_sync_wire_pair()
-                            ms_bytes = 0 if k == 0 \
-                                else k * ms_pair[0] + C * ms_pair[1]
-                        part[:] = True
-                t._log_round(
-                    rnd, rnd0, aggregated,
-                    lambda: {k: float(v[i]) for k, v in metrics.items()},
-                    profile, meter, log_every, callback, history, state,
-                    extra=extra, model_sync_bytes=ms_bytes, wire_bytes=wire,
-                    engine="population")
-            done += seg
-        self._state = state
-        # a segment can END exactly on a window boundary — enter the new
-        # window now so caches/cohorts are current for save()/stats
-        w_next = self.window_of(rnd0 + num_rounds)
-        if w_next != self._window:
+        with self.telemetry.timed("chunk/run", rounds=num_rounds):
+            rnd0 = t.method.batches_trained(self.fsl, state) // self.fsl.h
+            pool = self.device_pool()
+            history: List[dict] = []
+            profile = None
+            C = self.cohort_size
+            fault_active = not self.faults.is_null
+            blocking = t.method.downloads_gradients
+            ftrace = fstats = surv = part = part_dev = None
+            unit_bytes = ms_pair = None
+            dropped_updates = 0
             if fault_active:
-                self._state = self._close_window(self._state)
-            self._advance_window(w_next)
-        if self.telemetry.enabled:
-            self.telemetry.run_summary(
-                "population", comm=meter,
-                population=self.population_summary(history),
-                participation=t.participation_summary())
-        return self._state, history
+                ftrace = t._plan_faults(rnd0 + num_rounds)
+                fstats = t._fault_stats
+                surv = ftrace.survives(blocking)
+                part = np.ones(C, bool)
+                part_dev = jnp.ones(C, jnp.float32)
+            done = 0
+            while done < num_rounds:
+                r0 = rnd0 + done
+                w0 = self.window_of(r0)
+                if w0 != self._window:
+                    if fault_active:
+                        state = self._close_window(state)
+                    self._state = state
+                    self._advance_window(w0)
+                    state = self._state
+                seg = min(chunk, num_rounds - done)
+                if not self.refresh or fault_active:
+                    # faults also cut segments at window boundaries, so an
+                    # empty window can be repaired host-side before the next
+                    # cohort trains on its rows
+                    s = 1
+                    while s < seg and self.window_of(r0 + s) == w0:
+                        s += 1
+                    seg = s
+                with self.telemetry.timed("chunk/build", window=w0,
+                                          rounds=seg):
+                    plans = []
+                    for i in range(seg):
+                        w = self.window_of(r0 + i)
+                        ids = self.cohort_for(w)
+                        plans.append(self.data.round_indices(ids, r0 + i))
+                    sample = t.pool_round_spec(pool, plans[0].shape)
+                    if self._payload_bytes is None:
+                        up_spec, reply_spec = t.method.payload_specs(
+                            self.bundle, self.fsl, sample)
+                        self._payload_bytes = (
+                            t.transport.uplink_payload_bytes(up_spec),
+                            t.transport.downlink_payload_bytes(reply_spec)
+                            if reply_spec is not None else 0)
+                    for i in range(seg):
+                        w = self.window_of(r0 + i)
+                        self._record_window(w, self.cohort_for(w), r0 + i)
+                    if meter is not None and cost_model is not None \
+                            and profile is None:
+                        batch_size = jax.tree_util.tree_leaves(
+                            sample[1])[0].shape[2]
+                        profile = t.comm_profile(cost_model, batch_size,
+                                                 batch=sample)
+                    idx = jnp.asarray(np.stack(plans))
+                    lrs = jnp.asarray([t.lr_at(r0 + i) for i in range(seg)],
+                                      jnp.float32)
+                with self.telemetry.timed("chunk/dispatch", window=w0,
+                                          rounds=seg):
+                    if fault_active:
+                        mk = jnp.asarray(surv[r0:r0 + seg], jnp.float32)
+                        state, metrics, agg_mask, part_dev = \
+                            t.masked_pool_chunk_fn(state, pool, idx, lrs, mk,
+                                                   part_dev)
+                    else:
+                        state, metrics, agg_mask = t.pool_chunk_fn(state, pool,
+                                                                   idx, lrs)
+                with self.telemetry.timed("chunk/fetch", window=w0,
+                                          rounds=seg):
+                    agg_mask = np.asarray(agg_mask)
+                    metrics = {k: np.asarray(v) for k, v in metrics.items()}
+                with self.telemetry.timed("chunk/log", window=w0, rounds=seg):
+                    for i in range(seg):
+                        rnd = r0 + i
+                        aggregated = bool(agg_mask[i])
+                        extra = ms_bytes = wire = None
+                        if fault_active:
+                            part &= surv[rnd]
+                            if profile is not None:
+                                if unit_bytes is None:
+                                    unit_bytes = profile.unit_wire_bytes(
+                                        C, t._uploads_per_round())
+                                wire = accumulate_round(
+                                    fstats, self.faults, ftrace, rnd,
+                                    *unit_bytes, blocking, FRAME_BYTES)
+                            if aggregated:
+                                k = int(part.sum())
+                                if k == 0:
+                                    self._window_empty = True
+                                    warnings.warn(
+                                        f"fault model {self.faults.name!r} "
+                                        f"admitted no clients at the round-"
+                                        f"{rnd + 1} aggregation; FedAvg "
+                                        "skipped (no-op)")
+                                dropped_updates += C - k
+                                fstats.windows += 1
+                                fstats.participants.append(k)
+                                if k == 0:
+                                    fstats.empty_windows += 1
+                                extra = {"participants": k,
+                                         "dropped_updates": dropped_updates,
+                                         "fault_retries": fstats.retries,
+                                         "fault_drops": (fstats.crash_drops
+                                                         + fstats.wire_drops)}
+                                if profile is not None:
+                                    if ms_pair is None:
+                                        ms_pair = t._model_sync_wire_pair()
+                                    ms_bytes = 0 if k == 0 \
+                                        else k * ms_pair[0] + C * ms_pair[1]
+                                part[:] = True
+                        t._log_round(
+                            rnd, rnd0, aggregated,
+                            lambda: {k: float(v[i])
+                                     for k, v in metrics.items()},
+                            profile, meter, log_every, callback, history,
+                            state,
+                            extra=extra, model_sync_bytes=ms_bytes,
+                            wire_bytes=wire, engine="population")
+                done += seg
+            self._state = state
+            # a segment can END exactly on a window boundary — enter the new
+            # window now so caches/cohorts are current for save()/stats
+            w_next = self.window_of(rnd0 + num_rounds)
+            if w_next != self._window:
+                if fault_active:
+                    self._state = self._close_window(self._state)
+                self._advance_window(w_next)
+            if self.telemetry.enabled:
+                self.telemetry.run_summary(
+                    "population", comm=meter,
+                    population=self.population_summary(history),
+                    participation=t.participation_summary())
+            return self._state, history

@@ -6,17 +6,27 @@ counters/gauges, and emit *spans* — either **sim** spans placed on the
 async engine's simulated clock (per-client compute, per-attempt wire
 transfers, retry backoffs, server service, outages, model-sync
 barriers), or **host** spans measured with ``time.perf_counter`` (the
-compiled path's chunk build/dispatch phases).  Exporters render the
-accumulated state as JSONL, Prometheus text exposition, or Chrome
+compiled path's chunk build/dispatch/fetch/log phases).  Exporters render
+the accumulated state as JSONL, Prometheus text exposition, or Chrome
 trace-event JSON (:mod:`repro.telemetry.export`).
+
+Every :meth:`Telemetry.timed` span, on the null recorder too, also opens a
+``jax.profiler.TraceAnnotation`` of its name: whenever a ``jax.profiler``
+session runs, the span lands in the trace's host plane on the device ops'
+clock (one flag check when none runs).  An enabled recorder labels each
+timed span with ``compiles``, the backend compiles JAX reported during it,
+and keeps the counters ``xla_compiles_total`` and
+``compile_seconds_total{phase=trace|lower|compile}`` since its creation
+(:mod:`repro.telemetry.compiles`).
 
 The hard contract (rule T001, ``tests/test_telemetry.py``): telemetry is
 **observation-only**.  A disabled recorder is the `NullTelemetry`
-singleton whose every method is a pass — engines guard their emission
-sites with ``if telemetry.enabled:`` so the off path costs one attribute
-read.  An enabled recorder only ever runs on the host, AFTER device
-values have already been fetched by the engines' existing post-chunk /
-post-step mirrors — it never adds a host callback, never touches the
+singleton whose every method is a pass, its timed spans profiler
+annotations alone — engines guard their emission sites with ``if
+telemetry.enabled:`` so the off path costs one attribute read.  An
+enabled recorder only ever runs on the host, AFTER device values have
+already been fetched by the engines' existing post-chunk / post-step
+mirrors — it never adds a host callback, never touches the
 donated ``lax.scan`` body, and never changes a compiled program
 (fingerprint-checked by ``repro.analysis.audit_telemetry``).  Params and
 history are bitwise-identical with telemetry on vs. off in all four
@@ -27,7 +37,10 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Mapping, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.accounting import flat_record
+from repro.telemetry import compiles
 from repro.telemetry.record import (make_round_record, make_summary_record,
                                     validate_record)
 
@@ -63,9 +76,11 @@ class Span:
 
 
 class _HostTimer:
-    """Context manager backing :meth:`Telemetry.timed`."""
+    """Context manager backing :meth:`Telemetry.timed`: a profiler
+    annotation, plus the span and its compile count for the recorder."""
 
-    __slots__ = ("_tele", "_name", "_track", "_labels", "_t0")
+    __slots__ = ("_tele", "_name", "_track", "_labels", "_t0", "_c0",
+                 "_annotation")
 
     def __init__(self, tele: "Telemetry", name: str, track: str,
                  labels: Dict[str, Any]):
@@ -73,15 +88,22 @@ class _HostTimer:
         self._name = name
         self._track = track
         self._labels = labels
+        self._annotation = TraceAnnotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
+        self._c0 = compiles.snapshot().compiles
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tele.host_span(self._name, self._t0,
-                             time.perf_counter() - self._t0,
-                             track=self._track, **self._labels)
+        dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        tele = self._tele
+        tele.host_span(self._name, self._t0, dur, track=self._track,
+                       compiles=compiles.snapshot().compiles - self._c0,
+                       **self._labels)
+        tele.note_compiles()
         return False
 
 
@@ -96,6 +118,8 @@ class Telemetry:
         self.gauges: Dict[LabelKey, float] = {}
         self.spans: List[Span] = []
         self.records: List[Dict[str, Any]] = []
+        compiles.install()
+        self._compiles0 = compiles.snapshot()
 
     # -- scalars -------------------------------------------------------------
     def counter(self, name: str, value: float = 1, **labels):
@@ -106,6 +130,20 @@ class Telemetry:
     def gauge(self, name: str, value: float, **labels):
         """Set the labelled gauge ``name`` to its latest ``value``."""
         self.gauges[_key(name, labels)] = value
+
+    def compile_tally(self) -> "compiles.Tally":
+        """What JAX traced, lowered and compiled since this recorder was
+        made (process-wide: every thread's programs count)."""
+        return compiles.snapshot() - self._compiles0
+
+    def note_compiles(self):
+        """Bring ``xla_compiles_total`` and ``compile_seconds_total``
+        up to :meth:`compile_tally`."""
+        t = self.compile_tally()
+        self.counters[_key("xla_compiles_total", {})] = t.compiles
+        for phase in compiles.PHASES:
+            self.counters[_key("compile_seconds_total",
+                               {"phase": phase})] = t.secs(phase)
 
     # -- spans ---------------------------------------------------------------
     def sim_span(self, name: str, start: float, dur: float, track: str,
@@ -119,7 +157,9 @@ class Telemetry:
         self.spans.append(Span(name, start, dur, track, "host", labels))
 
     def timed(self, name: str, track: str = "host", **labels):
-        """``with tele.timed("chunk/build"):`` — a real host-side span."""
+        """``with tele.timed("chunk/build"):`` — a real host-side span,
+        labelled with the backend compiles during it, and a profiler
+        annotation of ``name``."""
         return _HostTimer(self, name, track, labels)
 
     # -- records -------------------------------------------------------------
@@ -155,6 +195,7 @@ class Telemetry:
             summary.update(flat_record(value, f"{section}."))
         rec = make_summary_record(engine, summary)
         self.records.append(validate_record(rec))
+        self.note_compiles()
         for k, v in summary.items():
             if isinstance(v, (int, float)) and not isinstance(v, bool):
                 self.gauge(k, float(v), engine=engine)
@@ -181,26 +222,17 @@ class Telemetry:
         export_trace(self, path)
 
 
-class _NullTimer:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_TIMER = _NullTimer()
-
-
 class NullTelemetry(Telemetry):
-    """The disabled recorder: every method is a no-op, ``enabled`` is
-    False so engines skip even argument construction on hot paths.  A
-    single module-level instance (`NULL_TELEMETRY`) is shared by every
-    trainer that didn't ask for telemetry."""
+    """The disabled recorder: every method is a no-op (``timed`` opens only
+    the profiler annotation), ``enabled`` is False so engines skip even
+    argument construction on hot paths.  A single module-level instance
+    (`NULL_TELEMETRY`) is shared by every trainer that didn't ask for
+    telemetry."""
 
     enabled = False
+
+    def __init__(self):
+        self.counters, self.gauges, self.spans, self.records = {}, {}, [], []
 
     def counter(self, name, value=1, **labels):
         pass
@@ -214,8 +246,14 @@ class NullTelemetry(Telemetry):
     def host_span(self, name, start, dur, track="host", **labels):
         pass
 
+    def compile_tally(self):
+        return compiles.Tally()
+
+    def note_compiles(self):
+        pass
+
     def timed(self, name, track="host", **labels):
-        return _NULL_TIMER
+        return TraceAnnotation(name)
 
     def round_record(self, *a, **k):
         pass

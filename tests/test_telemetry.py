@@ -14,7 +14,8 @@ that this file pins down:
     ``FaultStats.retry_seconds`` (non-blocking methods), compute spans
     to ``AsyncStats.compute_time``, serve spans to
     ``AsyncStats.server_busy`` — all exactly, not approximately;
-  - the compiled path's real host spans (chunk build vs execute);
+  - the compiled path's real host spans (chunk build, dispatch, fetch,
+    log), their profiler annotations and the compile counters;
   - the shared ``Recordable.to_record`` flattening and the zero-round
     summary guards;
   - the failure-aware analytic wall-clock estimate against the async
@@ -356,21 +357,99 @@ def test_async_blocking_method_trace():
 # ---------------------------------------------------------------------------
 
 
+CHUNK_SPANS = ("chunk/build", "chunk/dispatch", "chunk/fetch", "chunk/log")
+
+
 def test_compiled_chunk_spans():
     bundle, fsl, fed = _setup("cse_fsl")
     tele = Telemetry()
     tr = Trainer(bundle, fsl, donate=False, telemetry=tele)
     tr.run_compiled(tr.init(0), FederatedBatcher(fed, 4, fsl.h, seed=0),
                     5, chunk=2)
-    builds = [s for s in tele.spans if s.name == "chunk/build"]
-    execs = [s for s in tele.spans if s.name == "chunk/execute"]
-    assert len(builds) == len(execs) == 3          # ceil(5 / 2)
-    assert all(s.cat == "host" and s.dur >= 0 for s in builds + execs)
-    assert [s.labels["chunk"] for s in execs] == [0, 1, 2]
-    # first dispatch of each distinct chunk length pays the compile:
-    # R=2 (chunks 0,1) and the trailing R=1 (chunk 2)
-    assert [s.labels["first_dispatch"] for s in execs] == \
-        [True, False, True]
+    by = {n: [s for s in tele.spans if s.name == n] for n in CHUNK_SPANS}
+    assert [len(v) for v in by.values()] == [3] * 4      # ceil(5 / 2)
+    spans = [s for v in by.values() for s in v]
+    assert all(s.cat == "host" and s.dur >= 0 for s in spans)
+    assert all([s.labels["chunk"] for s in v] == [0, 1, 2]
+               for v in by.values())
+    # each chunk's spans run in order, one after the other
+    for c in range(3):
+        seq = [by[n][c] for n in CHUNK_SPANS]
+        assert all(a.start + a.dur <= b.start for a, b in zip(seq, seq[1:]))
+    # the dispatch of each new chunk length compiles, measured: R=2
+    # (chunk 0) and the trailing R=1 (chunk 2)
+    assert [s.labels["compiles"] for s in by["chunk/dispatch"]] == [1, 0, 1]
+    assert all(s.labels["compiles"] == 0 for n in ("chunk/fetch",
+                                                   "chunk/log")
+               for s in by[n])
+    # one "chunk/run" span holds the whole call
+    run, = [s for s in tele.spans if s.name == "chunk/run"]
+    assert all(run.start <= s.start and s.start + s.dur <= run.start + run.dur
+               for s in spans)
+    assert run.labels == {"rounds": 5, "compiles": sum(
+        s.labels["compiles"] for s in spans)}
+
+
+def test_compile_counter_counts_a_new_chunk_length():
+    bundle, fsl, fed = _setup("cse_fsl")
+    tele = Telemetry()
+    tr = Trainer(bundle, fsl, donate=False, telemetry=tele)
+    batcher = FederatedBatcher(fed, 4, fsl.h, seed=0)
+    key = ("xla_compiles_total", ())
+    state, _ = tr.run_compiled(tr.init(0), batcher, 4, chunk=2)
+    before = tele.counters[key]
+    assert before >= 1
+    state, _ = tr.run_compiled(state, batcher, 2, chunk=2)   # compiled R=2
+    assert tele.counters[key] == before
+    tr.run_compiled(state, batcher, 1, chunk=2)              # forced R=1
+    assert tele.counters[key] == before + 1
+    dispatch = [s for s in tele.spans if s.name == "chunk/dispatch"]
+    assert dispatch[-1].labels == {"compiles": 1, "chunk": 0, "rounds": 1}
+    assert tele.compile_tally().compiles == before + 1
+    seconds = {p: tele.counters[("compile_seconds_total", (("phase", p),))]
+               for p in ("trace", "lower", "compile")}
+    assert all(v > 0 for v in seconds.values())
+    text = tele.prometheus_text()
+    assert "# TYPE repro_xla_compiles_total counter" in text
+    assert f"repro_xla_compiles_total {before + 1}" in text
+    assert 'repro_compile_seconds_total{phase="lower"}' in text
+
+
+def test_compile_tally_counts_nested_traces_once():
+    from repro.telemetry import compiles
+    lis = compiles._Listener()
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    lis(trace, 1.0, 2.0)            # an inner jit, traced inside ...
+    lis(trace, 1.5, 1.75)           # (deeper still)
+    lis(trace, 0.5, 3.0)            # ... its caller, which ends last
+    lis(trace, 4.0, 4.5)            # a later, separate trace
+    lis("/jax/core/compile/backend_compile_duration", 5.0, 7.0)
+    lis("/some/other/event", 0.0, 9.0)
+    t = lis.snapshot()
+    assert t.counts == (4, 0, 1) and t.compiles == 1
+    assert t.seconds == (3.0, 0.0, 2.0)
+    d = t - compiles.Tally((1, 0, 0), (0.5, 0.0, 0.0))
+    assert (d.count("trace"), d.secs("trace"), d.secs("compile")) == \
+        (3, 2.5, 2.0)
+
+
+def test_timed_spans_reach_the_profiler_trace(tmp_path):
+    """With telemetry off, the chunk runner's spans are still profiler
+    annotations: a jax.profiler trace's host plane holds each of them,
+    once per chunk."""
+    bundle, fsl, fed = _setup("cse_fsl")
+    tr = Trainer(bundle, fsl, donate=False)
+    state = tr.init(0)
+    batcher = FederatedBatcher(fed, 4, fsl.h, seed=0)
+    with jax.profiler.trace(str(tmp_path)):
+        tr.run_compiled(state, batcher, 5, chunk=2)
+    path, = tmp_path.glob("**/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    names = [e.name for plane in pd.planes if plane.name.startswith("/host")
+             for line in plane.lines for e in line.events]
+    assert {n: names.count(n) for n in CHUNK_SPANS + ("chunk/run",)} == \
+        {**dict.fromkeys(CHUNK_SPANS, 3), "chunk/run": 1}
+    assert not NULL_TELEMETRY.spans
 
 
 # ---------------------------------------------------------------------------
