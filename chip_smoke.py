@@ -11,9 +11,10 @@ a fixed seed:
   - "train": cse_fsl, 4 clients, h=2, B=8, S=128, 4 rounds in chunks of
     2, codec none.  Every per-round loss must be finite.
   - "int8": the same run with the int8 uplink codec.  The Pallas quantize
-    kernel must be compiled natively into the chunk program (a
-    ``tpu_custom_call``, not interpret mode), and the metered uplink bytes
-    must equal ``Int8Codec.wire_bytes`` of the payload times the uploads.
+    kernel must be compiled natively into the chunk program (a Mosaic
+    custom call named ``quantize_2d``, not interpret mode), and the
+    metered uplink bytes must equal ``Int8Codec.wire_bytes`` of the
+    payload times the uploads.
   - "population" (``--chips 4`` only): 10,000 virtual clients, cohorts of
     8, with the cohort state sharded over every chip (``--mesh host``),
     against the same run on one chip.  The state must span all four chips
@@ -79,7 +80,9 @@ def run_train(name, extra, size="full"):
 
 def check_kernel_native(res):
     """The lowered chunk program holds the quantize kernel as a Mosaic
-    custom call — compiled for the chip, not interpreted."""
+    custom call, compiled for the chip and not interpreted; it is found
+    by its kernel name, since the aux head's fused CE kernels are Mosaic
+    custom calls in every codec's program."""
     import jax
     import jax.numpy as jnp
     tr, fsl = res.trainer, res.trainer.fsl
@@ -87,11 +90,14 @@ def check_kernel_native(res):
     idx = jax.ShapeDtypeStruct((2, fsl.num_clients, fsl.h, 8), jnp.int32)
     lrs = jax.ShapeDtypeStruct((2,), jnp.float32)
     text = tr.pool_chunk_fn.lower(res.state, pool, idx, lrs).as_text()
-    n = text.count("tpu_custom_call")
+    n = text.count('kernel_name = "quantize_2d"')
     if n == 0:
-        raise AssertionError("[int8] no tpu_custom_call in the chunk "
-                             "program: the quantize kernel was interpreted")
-    print(f"[int8] chunk program holds {n} tpu_custom_call sites")
+        raise AssertionError("[int8] no quantize_2d Mosaic kernel in the "
+                             "chunk program: the quantize kernel was "
+                             "interpreted")
+    fused = text.count('kernel_name = "fused_ce_fwd"')
+    print(f"[int8] chunk program holds {n} quantize_2d and {fused} "
+          "fused_ce_fwd Mosaic kernel sites")
 
 
 def check_uplink_bytes(res, rounds=4):

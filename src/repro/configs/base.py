@@ -75,7 +75,7 @@ class ModelConfig:
     # numerics
     dtype: str = "float32"          # activation / param dtype
     remat: bool = False             # checkpoint each scanned layer (train)
-    use_pallas: bool = False        # route hot spots through repro.kernels
+    use_pallas: bool = False        # SSM scan / SWA attention via repro.kernels
     # dry-run roofline lowering: fully unroll depth/chunk scans so
     # cost_analysis (which visits a while body once) counts every layer.
     dryrun_unroll: bool = False

@@ -191,6 +191,7 @@ def quantize_2d(x, bits=None, *, seed=None, fmt: str = "int8", bt: int = 8,
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=params,
+            name="quantize_2d",
         )(jnp.asarray(seed, jnp.int32).reshape(1), x)
         return q[:r, :c], scales[:nr]
 
@@ -214,6 +215,7 @@ def quantize_2d(x, bits=None, *, seed=None, fmt: str = "int8", bt: int = 8,
         out_shape=out_shape,
         compiler_params=params,
         interpret=interpret,
+        name="quantize_2d",
     )(x, bits.astype(jnp.uint32))
     return q[:r, :c], scales[:nr]
 

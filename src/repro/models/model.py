@@ -25,6 +25,7 @@ from jax import lax
 from repro.common import dtype_of
 from repro.configs.base import ModelConfig
 from repro.core.stages import AUX_CE
+from repro.kernels import ops
 from repro.models import layers as L
 from repro.models.blocks import (BLOCKS, Ctx, attn_cache_spec, block_cache_spec,
                                  block_kind, dense_apply, dense_init)
@@ -265,14 +266,47 @@ def chunked_ce(x, logits_fn, labels, chunk: int = 128, unroll: bool = False):
     return total / nc
 
 
-def head_ce(cfg: ModelConfig, pre, head_w, labels):
-    """CE of ``pre @ head_w`` vs labels; Pallas fused kernel when enabled.
+# widest head input (contracting width) whose CE takes the fused kernel
+FUSED_CE_MAX_WIDTH = 256
 
-    pre: [B,S,r]; head_w: [r,V]; labels: [B,S].  The fused kernel never
-    materializes [B*S, V] logits (see kernels/fused_ce.py).
+
+def fused_ce_wins(pre) -> bool:
+    """True when the head CE of ``pre`` takes the fused kernel: a narrow
+    head (contracting width <= ``FUSED_CE_MAX_WIDTH``) in a program where
+    the kernel runs compiled (``ops.fused_ce_native``: on a TPU, not
+    partitioned over a mesh)."""
+    return pre.shape[-1] <= FUSED_CE_MAX_WIDTH and ops.fused_ce_native(pre)
+
+
+def head_ce(cfg: ModelConfig, pre, head_w, labels):
+    """Mean CE of ``pre @ head_w`` vs labels.  pre: [B,S,r]; head_w: [r,V];
+    labels: [B,S].
+
+    The path follows from what the code observes, not from a flag
+    (``fused_ce_wins``):
+
+    - On a TPU, in a program no mesh partitions, with a contracting width
+      r <= ``FUSED_CE_MAX_WIDTH``: the vocab-tiled Pallas kernel
+      (``ops.fused_ce``).  The [B*S, V] logits live only in VMEM tiles,
+      in f32 from the operands' own dtype, and backward recomputes them
+      from the row-wise LSE.
+    - Otherwise the sequence-chunked XLA path (``chunked_ce``), which
+      writes each chunk's logits to HBM and reads them back.  That is
+      every head off a TPU (the CPU tests) and in a program sharded over
+      several chips (XLA cannot partition a Mosaic kernel).
+
+    The width rule keeps the server's LM head (r = d_model = 1,024) and an
+    ``mlp`` aux head (r = d_model) on the XLA path, so that the server
+    stage stays the unchanged control beside the rank-128 aux head.  It
+    is not a measured crossover.  The arithmetic puts one near r ~ 1,000:
+    the fused backward recomputes every logit, 2r FLOP, to save its HBM
+    round trips, ~8-12 bytes a logit, and on v5e a byte costs ~240 FLOP
+    (197 TFLOP/s against 819 GB/s).  But one v5e timing at r = 1,024,
+    T = 1,024 found the fused kernel faster, 9.3 against 13.0 ms for loss
+    and gradients, so the wider heads may gain too once a benchmark cell
+    measures them.
     """
-    if cfg.use_pallas:
-        from repro.kernels import ops
+    if fused_ce_wins(pre):
         t = pre.shape[0] * pre.shape[1]
         return ops.fused_ce(pre.reshape(t, -1), head_w, labels.reshape(t))
     return chunked_ce(pre, lambda xc: xc @ head_w, labels,

@@ -9,11 +9,14 @@ a chip.  The topology is described inside a fixture, never at import,
 and the tests skip where it cannot be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+import numpy as np
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.kernels import fused_ce as ce_mod
 from repro.kernels import ops
@@ -68,23 +71,55 @@ def test_quantize_compiles(one_chip, fmt, path):
         fn = lambda x, b: qk.quantize_2d(x, b, fmt=fmt, interpret=False)
         rnd = _spec((1024, 1024), jnp.uint32, one_chip)
     compiled = _compile(fn, x, rnd)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "quantize_2d" in compiled.as_text()
 
 
 def test_fused_ce_forward_and_backward_compile(one_chip):
-    """The LM-head cross entropy at qwen3's 151,936-token vocabulary."""
+    """The LM-head cross entropy at qwen3's 151,936-token vocabulary and
+    the server head's width, one token block."""
     t, d, v = 1024, 1024, 151_936
     bt, bv = ops._ce_blocks(t, d, v)
     x = _spec((t, d), jnp.bfloat16, one_chip)
     w = _spec((d, v), jnp.bfloat16, one_chip)
     lab = _spec((t,), jnp.int32, one_chip)
     lse = _spec((t,), jnp.float32, one_chip)
-    fwd = _compile(lambda x, w, lab: ce_mod.fused_ce_fwd(
-        x, w, lab, bt=bt, bv=bv, interpret=False), x, w, lab)
+    fwd = _compile(lambda x, w: ce_mod.fused_ce_fwd(
+        x, w, bt=bt, bv=bv, interpret=False), x, w)
     bwd = _compile(lambda x, w, lab, lse: ce_mod.fused_ce_bwd(
-        x, w, lab, lse, bt=bt, bv=bv, interpret=False), x, w, lab, lse)
-    assert "tpu_custom_call" in fwd.as_text()
-    assert "tpu_custom_call" in bwd.as_text()
+        x, w, lab, lse, 1.0 / t, bt=bt, bv=bv, interpret=False),
+        x, w, lab, lse)
+    assert "fused_ce_fwd" in fwd.as_text()
+    assert "fused_ce_bwd" in bwd.as_text()
+
+
+def test_fused_ce_aux_head_compiles_vmapped(one_chip, monkeypatch):
+    """The aux head's CE as the chunk program runs it: rank 128, T = 1,024
+    tokens, 151,936 classes, bf16, value and gradient vmapped over 4
+    clients (a grid axis), through ``ops.fused_ce`` with the backend
+    probe steered to the chip's branch."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    n, t, d, v = 4, 1024, 128, 151_936
+    assert ops._ce_blocks(t, d, v)[0] == t          # one token block
+    fn = jax.vmap(jax.value_and_grad(ops.fused_ce, argnums=(0, 1)))
+    text = _compile(fn, _spec((n, t, d), jnp.bfloat16, one_chip),
+                    _spec((n, d, v), jnp.bfloat16, one_chip),
+                    _spec((n, t), jnp.int32, one_chip)).as_text()
+    assert "fused_ce_fwd" in text and "fused_ce_bwd" in text
+    assert "fused_ce_bwd_dx" not in text            # the single-kernel path
+
+
+def test_fused_ce_two_kernel_backward_compiles(one_chip):
+    """Too many tokens for one VMEM block: the dx and dw kernels."""
+    t, d, v = 16_384, 128, 151_936
+    bt, bv = ops._ce_blocks(t, d, v)
+    assert bt < t
+    text = _compile(lambda x, w, lab, lse: ce_mod.fused_ce_bwd(
+        x, w, lab, lse, 1.0 / t, bt=bt, bv=bv, interpret=False),
+        _spec((t, d), jnp.bfloat16, one_chip),
+        _spec((d, v), jnp.bfloat16, one_chip),
+        _spec((t,), jnp.int32, one_chip),
+        _spec((t,), jnp.float32, one_chip)).as_text()
+    assert "fused_ce_bwd_dx" in text and "fused_ce_bwd_dw" in text
 
 
 def test_ssm_scan_compiles(one_chip):
@@ -112,16 +147,17 @@ def test_full_width_chunk_program_fits_one_chip(one_chip, monkeypatch,
                                                 codec):
     """The donated 2-round chunk program that ``chip_smoke.py`` runs:
     qwen3-0.6b at full width, cse_fsl, 4 clients, h=2, B=8, S=128,
-    batches gathered in-scan from the device pool.  The int8 uplink
-    carries the quantize kernel on its in-kernel PRNG path, as on the
-    chip."""
+    batches gathered in-scan from the device pool.  The aux head's CE
+    runs the fused kernels in both codecs; the int8 uplink carries the
+    quantize kernel on its in-kernel PRNG path, as on the chip."""
     from repro.configs.base import FSLConfig
     from repro.configs.registry import get_config
     from repro.core.bundle import transformer_bundle
     from repro.core.trainer import Trainer
-    # steer the backend probe to the chip's branch (this process sees a
-    # CPU): native kernel, seed-driven randomness
+    # steer the backend probes to the chip's branch (this process sees a
+    # CPU): native kernels, seed-driven randomness, the aux head's fused CE
     monkeypatch.setattr(qk, "_interpret", lambda: False)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
     n, h, b, s, pool = 4, 2, 8, 128, 2048
     fsl = FSLConfig(num_clients=n, h=h, lr=0.1, method="cse_fsl",
                     codec=codec)
@@ -136,4 +172,41 @@ def test_full_width_chunk_program_fits_one_chip(one_chip, monkeypatch,
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB > 16 GiB"
-    assert ("tpu_custom_call" in compiled.as_text()) == (codec == "int8")
+    text = compiled.as_text()
+    assert "fused_ce_fwd" in text and "fused_ce_bwd" in text
+    assert ("quantize_2d" in text) == (codec == "int8")
+
+
+def test_sharded_cohort_chunk_keeps_the_xla_ce(topo, monkeypatch):
+    """The cohort engine's ``--mesh host`` chunk program on all four chips
+    of the described topology, 8 clients split over "data" (reduced
+    qwen3-0.6b).  XLA cannot partition a Mosaic kernel, so the aux head's
+    CE keeps the XLA path in a sharded program: it compiles with the
+    backend probe on the chip's branch, holds no fused CE kernel, and no
+    chip gathers the other chips' stacked aux ``up``."""
+    from repro.configs.base import FSLConfig
+    from repro.configs.registry import get_config
+    from repro.core.bundle import transformer_bundle
+    from repro.core.trainer import Trainer
+    from repro.sharding import state_specs
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    n, h, b, s, pool = 8, 2, 8, 128, 2048
+    fsl = FSLConfig(num_clients=n, h=h, lr=0.1, method="cse_fsl")
+    tr = Trainer(transformer_bundle(get_config("qwen3-0.6b").reduced()), fsl)
+    abstract = jax.eval_shape(tr.init)
+    state = jax.tree_util.tree_map(
+        lambda x, spec: _spec(x.shape, x.dtype, NamedSharding(mesh, spec)),
+        abstract, state_specs(abstract, mesh=mesh, fsdp_server=False))
+    rep = NamedSharding(mesh, PartitionSpec())
+    tokens = _spec((pool, s), jnp.int32, rep)
+    text = tr.pool_chunk_fn.lower(
+        state, ({"tokens": tokens}, tokens),
+        _spec((2, n, h, b), jnp.int32, rep),
+        _spec((2,), jnp.float32, rep)).compile().as_text()
+    assert "fused_ce" not in text
+    up = ",".join(map(str, abstract["clients"]["params"]["aux"]["up"].shape))
+    gathered = {shape for line in text.splitlines() if "all-gather" in line
+                for shape in re.findall(r"\[([\d,]+)\]",
+                                        line.split("all-gather")[0])}
+    assert up not in gathered, f"the stacked aux up [{up}] is all-gathered"
