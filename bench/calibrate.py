@@ -1,17 +1,20 @@
 """Readings behind a cell's correctness limits, on the chip at the cell's size.
 
     python bench/calibrate.py --workload <name> --seeds 1,2,... \\
-        [--control-seeds 1,2,3] [--faults half_batch,altered] \\
+        [--control-seeds 1,2,3] [--faults half_batch,altered,...] \\
         [--fault-seeds 1,2,3]
 
 For every seed the program runs its first chunk from the seed (the same
 call and feed as a benchmark run's set-up), and the plain reference
 replays those rounds; each line of output gives the compared numbers of
-one reading:
+one reading (``codec_gap`` on the reference's uploads through the
+reading's own uplink codec; none for the control):
 
   program  the program as it is (the lower reading: the largest over seeds)
-  control  the reference computed in fp8 in the program's place
-  <fault>  the program with a fault of bench/faults.py planted
+  control  the cell's reference (its family's, through the harness)
+           computed in fp8 in the program's place
+  <fault>  the program with a fault of bench/faults.py planted at the
+           harness's ``program_bundle`` or ``make_trainer`` seam
 
 The ``unchanged`` fault reads 1 on ``delta_gap`` by construction (no leaf
 moves) and is not run.  A benchmark run never runs this script.
@@ -47,8 +50,10 @@ def main(argv=None):
 
     cell = harness.load_cell(ROOT, args.workload)
     readings = {}           # (kind, seed) -> (losses, deltas)
+    transports = {}         # kind -> the program's uplink transport
 
     def drive(kind, prog, seeds):
+        transports[kind] = prog.trainer.transport
         for seed in seeds:
             t = time.perf_counter()
             prog.start(seed)
@@ -64,12 +69,15 @@ def main(argv=None):
     del prog
     for name in [f for f in args.faults.split(",") if f]:
         gc.collect()
-        sound = harness.program_bundle
-        harness.program_bundle = faults.BUNDLE_FAULTS[name]
+        seam = "program_bundle" if name in faults.BUNDLE_FAULTS \
+            else "make_trainer"
+        sound = getattr(harness, seam)
+        setattr(harness, seam, {**faults.BUNDLE_FAULTS,
+                                **faults.TRAINER_FAULTS}[name])
         try:
             prog = harness.Program(cell)
         finally:
-            harness.program_bundle = sound
+            setattr(harness, seam, sound)
         drive(name, prog, args.fault_seeds)
         del prog
     gc.collect()
@@ -77,7 +85,9 @@ def main(argv=None):
     for seed in sorted(set(args.seeds) | set(args.fault_seeds)
                        | set(args.control_seeds)):
         t = time.perf_counter()
-        ref_l, ref_d = harness.run_reference(cell, seed, abstract)
+        uploads = []
+        ref_l, ref_d = harness.run_reference(cell, seed, abstract,
+                                             uploads=uploads)
         print(f"# reference seed {seed}: {time.perf_counter() - t:.1f} s",
               file=sys.stderr, flush=True)
         if seed in args.control_seeds:
@@ -87,10 +97,14 @@ def main(argv=None):
             if s != seed:
                 continue
             loss_gap, delta_gap = harness.compare(losses, d, ref_l, ref_d)
+            gap = None if kind == "control" else harness.codec_gap(
+                cell, transports[kind], uploads, seed)
             print(json.dumps({"workload": args.workload, "kind": kind,
                               "seed": seed, "loss_gap": loss_gap,
-                              "delta_gap": delta_gap, "losses": losses,
-                              "ref_losses": ref_l}), flush=True)
+                              "delta_gap": delta_gap, "codec_gap": gap,
+                              "losses": losses, "ref_losses": ref_l}),
+                  flush=True)
+        del uploads
     return 0
 
 

@@ -8,6 +8,10 @@ run itself.
               mini-batch only, the mean taken over the rest
   altered     every upload's first token activation is negated where the
               client produces it
+  codec_skipped  in a coded mix, the uplink codec bills its coded bytes
+              but hands the server the upload as computed, un-quantized
+  codec_nearest  in a coded mix, the uplink codec rounds to the nearest
+              step instead of stochastically (biased, and cheaper)
 """
 from __future__ import annotations
 
@@ -61,5 +65,31 @@ def unchanged(bundle, fsl):
     return tr
 
 
+def codec_skipped(bundle, fsl):
+    """A trainer whose uplink codec keeps its name and wire bytes but
+    passes every upload through unchanged."""
+    from repro.core.trainer import Trainer
+    from repro.transport import resolve_transport
+    tp = resolve_transport(None, fsl)
+    coded = tp.uplink
+
+    class Skipped(type(coded)):
+        def roundtrip(self, payload, *, key=None):
+            return payload
+
+    return Trainer(bundle, fsl, transport=dataclasses.replace(
+        tp, uplink=Skipped(**dataclasses.asdict(coded))))
+
+
+def codec_nearest(bundle, fsl):
+    """A trainer whose quantizing uplink codec rounds to nearest."""
+    from repro.core.trainer import Trainer
+    from repro.transport import resolve_transport
+    tp = resolve_transport(None, fsl)
+    return Trainer(bundle, fsl, transport=dataclasses.replace(
+        tp, uplink=dataclasses.replace(tp.uplink, stochastic=False)))
+
+
 BUNDLE_FAULTS = {"half_batch": half_batch, "altered": altered}
-TRAINER_FAULTS = {"unchanged": unchanged}
+TRAINER_FAULTS = {"unchanged": unchanged, "codec_skipped": codec_skipped,
+                  "codec_nearest": codec_nearest}

@@ -6,6 +6,8 @@ Everything a cell needs is found by name from ``BENCHMARK.json``:
   bench/models/<family>.py       sizes -> the program's config, reference
   bench/flops/<family>.py        model FLOPs of one round
   bench/traffic/<traffic>.json   the training mix (see traffic.py)
+  bench/codecs/<codec>.py        the reference of the mix's uplink codec,
+                                 and the program kernel that runs it
   bench/workloads/<cell>.json    the limits of the correctness comparison
   bench/metrics/<metric>.py      one reader per per-layer metric
   bench/peaks.json               per-chip peaks keyed by device kind
@@ -94,13 +96,28 @@ def load_cell(root: str, workload: str) -> Cell:
                                     f"{workload}.json"))["limits"]
     if not limits:
         raise BenchError(f"workload {workload!r} compares no number")
+    models = load_module(os.path.join(bdir, "models", f"{family}.py"),
+                         f"bench_models_{family}")
+    if not callable(getattr(models, "reference", None)):
+        raise BenchError(f"bench/models/{family}.py has no reference(); "
+                         "a cell needs its family's plain reference")
+    mix = load_json(os.path.join(bdir, "traffic", f"{w['traffic']}.json"))
+    import codec
+    try:
+        uplink = codec.module(mix["codec"])
+    except ValueError as e:
+        raise BenchError(str(e)) from e
+    unknown = set(limits) - set(COMPARED)
+    if unknown:
+        raise BenchError(f"workload {workload!r} limits {sorted(unknown)}, "
+                         f"which no run reads; compared: {list(COMPARED)}")
+    if "codec_missing" in limits and not getattr(uplink, "KERNEL", None):
+        raise BenchError(f"codec {mix['codec']!r} names no program kernel "
+                         "to count on the trace (codec_missing)")
     wanted = lambda m: "workloads" not in m or workload in m["workloads"]
     return Cell(
-        name=workload, chips=int(w["chips"]), config=config,
-        mix=load_json(os.path.join(bdir, "traffic", f"{w['traffic']}.json")),
-        limits=limits,
-        family=load_module(os.path.join(bdir, "models", f"{family}.py"),
-                           f"bench_models_{family}"),
+        name=workload, chips=int(w["chips"]), config=config, mix=mix,
+        limits=limits, family=models,
         flops=load_module(os.path.join(bdir, "flops", f"{family}.py"),
                           f"bench_flops_{family}"),
         end_to_end=[m for m in bench["end_to_end"] if wanted(m)],
@@ -261,29 +278,87 @@ def compare(prog_losses, prog_deltas, ref_losses, ref_deltas):
     return loss_gap, delta_gap
 
 
-def run_reference(cell: Cell, seed: int, abstract, precision="fp32"):
+def run_reference(cell: Cell, seed: int, abstract, precision="fp32",
+                  uploads=None):
     """The reference's per-round losses and per-leaf change norms over the
-    first chunk's rounds, from the seed's weights and traffic."""
+    first chunk's rounds, from the seed's weights and traffic; the cell's
+    family (``bench/models/<family>.py``) brings the reference.  A list
+    given as ``uploads`` gets the reference's ``uploads``: ``(round,
+    client, upload)`` of every upload as computed, before the codec."""
     import jax
-    import reference
     import traffic as traffic_mod
     import weights
     mix = cell.mix
     tr = traffic_mod.Traffic(mix, cell.numbers["vocab"], seed)
-    ref = reference.Reference(cell.numbers, mix, precision=precision)
+    ref = cell.family.reference(cell.numbers, mix, precision)
     params, losses = ref.run(weights.make_params(abstract, seed), tr,
                              int(mix["rounds_per_chunk"]))
+    if uploads is not None:
+        uploads.extend(ref.uploads)
     now = jax.tree_util.tree_map(lambda a: a[None], params)
     d = deltas(now, weights.make_params(abstract, seed))
     return losses, d
 
 
-def checks_of(cell: Cell, loss_gap, delta_gap):
-    """The numbers that have a limit in the cell's workload file, each
-    beside it, and whether every one is within it."""
-    values = {"loss_gap": loss_gap, "delta_gap": delta_gap}
+def codec_gap(cell: Cell, transport, uploads, seed: int):
+    """Over the reference's uploads of the compared rounds, cast to the
+    model's type: the widest gap between 1 and the ratio of two errors,
+    the norm of (received - upload) through the program's uplink codec
+    (``transport``, the trainer's own) over that through the reference
+    codec (``bench/codecs/<codec>.py``), each decoded to the model's type.
+    A skipped codec reads 1; a coarser or biased one far above 0.
+
+    Each side's received upload leaves its own program in the model's
+    type: a cast to it inside ``err`` may be elided where the compiler
+    allows excess precision (it is on the TPU), which would compare the
+    program's rounded upload with an unrounded reference."""
+    import jax
+    import jax.numpy as jnp
+    import codec
+    dtype = jnp.dtype(cell.config["torch_dtype"])
+    ref_rt = codec.uplink(cell.mix["codec"], dtype)
+    prog_rt = jax.jit(transport.code_uplink)
+
+    @jax.jit
+    def err(received, x):
+        return jnp.linalg.norm(received.astype(jnp.float32)
+                               - x.astype(jnp.float32))
+
+    gap = 0.0
+    for rnd, client, x in uploads:
+        x = x.astype(dtype)
+        e_prog = float(err(prog_rt(x, transport.unit_key(rnd, client)), x))
+        e_ref = float(err(ref_rt(x.astype(jnp.float32),
+                                 codec.upload_key(seed, rnd, client)), x))
+        if e_ref > 0:
+            gap = max(gap, abs(e_prog / e_ref - 1.0))
+        elif e_prog > 0:
+            return math.inf
+    return gap
+
+
+def codec_missing(cell: Cell, summary, rounds: int):
+    """In a traced run: the share of the traced rounds' uploads (one a
+    client a round) for which the trace shows no call of the kernel that
+    the program's codec runs (``KERNEL`` of ``bench/codecs/<codec>.py``)."""
+    import codec
+    calls, _ = summary.kernel(codec.module(cell.mix["codec"]).KERNEL)
+    return max(0.0, 1.0 - calls / (rounds * cell.mix["clients"]))
+
+
+# the numbers a workload file may hold a limit for; one that is not read
+# in a run (codec_missing without a trace) is not compared there
+COMPARED = ("loss_gap", "delta_gap", "codec_gap", "codec_missing")
+
+
+def checks_of(cell: Cell, loss_gap, delta_gap, **read):
+    """The numbers that have a limit in the cell's workload file and are
+    read in this run, each beside it, and whether every one is within
+    it."""
+    values = {"loss_gap": loss_gap, "delta_gap": delta_gap, **read}
     checks = {k: {"value": values[k], "limit": lim}
-              for k, lim in cell.limits.items()}
+              for k, lim in cell.limits.items()
+              if values.get(k) is not None}
     ok = all(c["value"] <= c["limit"] for c in checks.values())
     return ok, checks
 
@@ -406,6 +481,7 @@ def run(args, t_start: float, root: str, require_tpu: bool = True):
     mem_peak = peak_memory(devs)
     st_bytes = state_bytes(prog.state, devs)
     abstract = prog.abstract
+    transport = prog.trainer.transport
     del prog
     gc.collect()
 
@@ -414,6 +490,7 @@ def run(args, t_start: float, root: str, require_tpu: bool = True):
     mix = cell.mix
     tokens = rounds * mix["clients"] * mix["h"] * mix["batch"] * mix["seq"]
     breakdown = None
+    read = {}
     if args.trace:
         import devtrace as trace_mod
         t = time.perf_counter()
@@ -429,6 +506,8 @@ def run(args, t_start: float, root: str, require_tpu: bool = True):
                             meter=meter.counts, metered_rounds=metered_rounds,
                             state_bytes=st_bytes)
         metrics = per_layer_metrics(cell, ctx)
+        if "codec_missing" in cell.limits:
+            read["codec_missing"] = codec_missing(cell, summary, rounds)
     else:
         values = {"tokens_per_s": tokens / elapsed,
                   "peak_hbm_gib": mem_peak / GIB,
@@ -436,9 +515,14 @@ def run(args, t_start: float, root: str, require_tpu: bool = True):
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in cell.end_to_end}
 
-    ref_losses, ref_deltas = run_reference(cell, args.seed, abstract)
+    uploads = [] if "codec_gap" in cell.limits else None
+    ref_losses, ref_deltas = run_reference(cell, args.seed, abstract,
+                                           uploads=uploads)
     loss_gap, delta_gap = compare(first, prog_deltas, ref_losses, ref_deltas)
-    correct, checks = checks_of(cell, loss_gap, delta_gap)
+    if uploads is not None:
+        read["codec_gap"] = codec_gap(cell, transport, uploads, args.seed)
+    del uploads
+    correct, checks = checks_of(cell, loss_gap, delta_gap, **read)
     return result_line(correct, rounds, failed, metrics, device, checks,
                        breakdown)
 

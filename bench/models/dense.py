@@ -1,6 +1,7 @@
 """Dense decoder family (Qwen2/Qwen3 style): the mapping from a published
 ``config.json`` plus the split's assumed sizes to the program's model
-config and to the numbers the reference and the FLOP count read."""
+config and to the numbers the reference and the FLOP count read, and the
+family's plain reference (``bench/reference.py``)."""
 from __future__ import annotations
 
 
@@ -38,3 +39,11 @@ def program_config(config: dict):
         rope_theta=m["rope_theta"], cut_layer=m["cut"],
         aux_rank=m["aux_rank"], dtype=config["torch_dtype"],
         remat=bool(a["remat"]), swa_window=0)
+
+
+def reference(numbers: dict, mix: dict, precision: str = "fp32"):
+    """The plain reference that replays a cell's rounds for this family:
+    ``run(params, traffic, rounds) -> (params, losses)``, after which its
+    ``uploads`` lists ``(round, client, upload)`` before the codec."""
+    import reference as dense_reference
+    return dense_reference.Reference(numbers, mix, precision=precision)

@@ -8,7 +8,8 @@ client step at a time.  It imports nothing of the program.  Per round:
      auxiliary head (RMSNorm, rank-r down projection, vocabulary up
      projection, mean cross entropy);
   2. it recomputes the smashed data of its last batch with its updated
-     stage (Alg. 1 line 9) and uploads it uncompressed (codec "none");
+     stage (Alg. 1 line 9) and uploads it through the mix's uplink codec
+     (``bench/codecs/<codec>.py``: the identity for "none");
   3. the server takes one SGD step per upload, in client order;
   4. FedAvg replaces every client's stage and head by their mean.
 
@@ -30,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+import codec
 
 HI = lax.Precision.HIGHEST
 
@@ -144,8 +147,7 @@ class Reference:
     def __init__(self, m, mix, precision="fp32"):
         self.m = m
         self.lr = float(mix["lr"])
-        if mix["codec"] != "none":
-            raise ValueError(f"reference has no codec {mix['codec']!r}")
+        self.uplink = codec.uplink(mix["codec"])
         P = precision
 
         def layer(p, x):
@@ -204,8 +206,10 @@ class Reference:
         one copy each, in the program's layout) on ``traffic``'s batches.
         Returns the params after the last FedAvg, in that layout, and
         per-round ``(client_loss, server_loss)``, each the mean over
-        clients as the program reports them."""
+        clients as the program reports them; ``self.uploads`` then holds
+        ``(round, client, upload)`` of every upload before the codec."""
         m = self.m
+        self.uploads = []
         cp, ap, sp = params["client"], params["aux"], params["server"]
         glob = {"embed": cp["embed"], "aux": ap,
                 "layers": _unstack(cp["blocks_stage"]["blocks"], m["cut"])}
@@ -226,6 +230,8 @@ class Reference:
                       for rows in plan[c]]
                 last = plan[c, -1]
                 sm = self.smashed(cl, jnp.asarray(x[last]))
+                self.uploads.append((rnd, c, sm))
+                sm = self.uplink(sm, codec.upload_key(traffic.seed, rnd, c))
                 uploads.append((sm, jnp.asarray(y[last])))
                 clients.append(cl)
                 closs.append(jnp.mean(jnp.stack(ls)))

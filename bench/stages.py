@@ -4,10 +4,9 @@ The chunk program names its stages with ``jax.named_scope``
 (``repro.core.stages``): ``client_step``, ``aux_ce``, ``client_update``,
 ``server_consume``, ``fedavg``.  XLA keeps each op's name path, and on a
 TPU the profiler writes it as the ``tf_op`` stat of the op's event
-metadata in the device plane (``jax.profiler.ProfileData`` shows event
-stats only, so :func:`op_paths` reads the xplane's protobuf itself).
-This module extends ``devtrace``'s reading with that path and
-reduces a trace to the self time of each stage in the traced window: an
+metadata in the device plane, which ``devtrace.events`` reads beside each
+op.  This module reduces a trace to the self time of each stage in the
+traced window: an
 op's self time is its duration minus what the ops nested in it on the same
 line cover (the scan loops of the chunk program hold their bodies' ops),
 and it counts for the innermost stage of the op's path, matched by path
@@ -37,7 +36,6 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import sys
 import time
 
@@ -50,125 +48,14 @@ import devtrace  # noqa: E402  (bench/ is on the path, as for run.py)
 STAGES = ("client_step", "aux_ce", "client_update", "server_consume",
           "fedavg")
 UNSCOPED = "unscoped"
-# the event-metadata stat that holds the op's name path (op_name)
-PATH_STAT = "tf_op"
-_WRAPPED = re.compile(r"^[\w.-]+\((.*)\)$")
-
-
-def _component(part: str) -> str:
-    """``transpose(jvp(aux_ce))`` -> ``aux_ce``."""
-    m = _WRAPPED.match(part)
-    while m:
-        part = m.group(1)
-        m = _WRAPPED.match(part)
-    return part
-
-
 def stage_of(path: str) -> str:
     """The innermost stage among the components of an op's name path."""
     stage = UNSCOPED
     for part in (path or "").split("/"):
-        part = _component(part)
+        part = devtrace.component(part)
         if part in STAGES:
             stage = part
     return stage
-
-
-def _varint(buf, i):
-    out = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        out |= (b & 0x7F) << shift
-        if b < 0x80:
-            return out, i
-        shift += 7
-
-
-def _fields(buf, lo, hi):
-    """``(field number, value)`` of the protobuf message ``buf[lo:hi]``;
-    a length-delimited value is its ``(start, end)`` in ``buf``."""
-    i = lo
-    while i < hi:
-        key, i = _varint(buf, i)
-        kind = key & 7
-        if kind == 0:
-            v, i = _varint(buf, i)
-        elif kind == 1:
-            v, i = buf[i:i + 8], i + 8
-        elif kind == 2:
-            n, i = _varint(buf, i)
-            v, i = (i, i + n), i + n
-        elif kind == 5:
-            v, i = buf[i:i + 4], i + 4
-        else:
-            raise ValueError(f"protobuf wire type {kind} at byte {i}")
-        yield key >> 3, v
-
-
-def _entry_value(buf, span):
-    """The value (field 2) of a protobuf map entry."""
-    for num, v in _fields(buf, *span):
-        if num == 2:
-            return v
-    return None
-
-
-def op_paths(path: str) -> dict:
-    """``{device plane: {op event name: name path}}`` from the ``tf_op``
-    stat of each device plane's event metadata (XSpace.planes = 1;
-    XPlane.name = 2, .event_metadata = 4, .stat_metadata = 5;
-    XEventMetadata.name = 2, .stats = 5; XStat.metadata_id = 1,
-    .str_value = 5, .ref_value = 7; XStatMetadata.name = 2)."""
-    with open(path, "rb") as f:
-        buf = f.read()
-    text = lambda span: buf[span[0]:span[1]].decode("utf-8", "replace")
-    out = {}
-    for num, plane in _fields(buf, 0, len(buf)):
-        if num != 1:
-            continue
-        name, events, stat_names = None, [], {}
-        for n, v in _fields(buf, *plane):
-            if n == 2:
-                name = text(v)
-            elif n == 4:
-                events.append(_entry_value(buf, v))
-            elif n == 5:
-                meta = _entry_value(buf, v)
-                fields = dict(_fields(buf, *meta))
-                stat_names[fields.get(1)] = text(fields[2]) \
-                    if 2 in fields else ""
-        if not name or not devtrace.DEVICE_PLANE.match(name):
-            continue
-        want = {i for i, s in stat_names.items() if s == PATH_STAT}
-        paths = {}
-        for ev in events:
-            op, scope = None, ""
-            for n, v in _fields(buf, *ev):
-                if n == 2:
-                    op = text(v)
-                elif n == 5:
-                    stat = dict(_fields(buf, *v))
-                    if stat.get(1) not in want:
-                        continue
-                    if 5 in stat:
-                        scope = text(stat[5])
-                    elif 7 in stat:
-                        scope = stat_names.get(stat[7], "")
-            if op is not None:
-                paths[op] = scope
-        out[name] = paths
-    return out
-
-
-def events(path: str) -> dict:
-    """``devtrace.events(path)`` plus ``"paths"``: per device, the name
-    path of each op, in the order of ``ev["ops"][device]``."""
-    ev = devtrace.events(path)
-    names = op_paths(path)
-    ev["paths"] = {d: [names.get(d, {}).get(n, "") for n, _, _ in ops]
-                   for d, ops in ev["ops"].items()}
-    return ev
 
 
 def window_of(ev: dict):
@@ -266,8 +153,8 @@ def main(argv, require_tpu: bool = True) -> int:
 
     def read_then_remove(trace_dir):
         t = time.perf_counter()
-        found["stages"] = stage_seconds(events(devtrace.find_xplane(
-            trace_dir)))
+        found["stages"] = stage_seconds(devtrace.events(
+            devtrace.find_xplane(trace_dir)))
         print(f"stages: trace read in {time.perf_counter() - t:.1f} s",
               file=sys.stderr)
         remove(trace_dir)

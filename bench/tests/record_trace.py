@@ -1,11 +1,12 @@
 """Record the small TPU trace that ``test_bench_stages.py`` reads.
 
-    python3 bench/tests/record_trace.py <out.xplane.pb.gz>
+    python3 bench/tests/record_trace.py <out.xplane.pb.gz> [--codec int8]
 
 A tiny dense cse_fsl program (2 clients, h=2, 2 layers cut after the
-first, d 64, vocab 256, B=1, S=32, 2 rounds a chunk) runs its compiled
-chunk once to warm up, then two chunks under ``jax.profiler``, each inside
-the benchmark's ``bench/chunk`` annotation, as a ``--trace 1`` run drives
+first, d 64, vocab 256, B=1, S=32, 2 rounds a chunk; uplink codec
+"none" unless ``--codec`` names another) runs its compiled chunk once to
+warm up, then two chunks under ``jax.profiler``, each inside the
+benchmark's ``bench/chunk`` annotation, as a ``--trace 1`` run drives
 them.  The xplane file is written gzipped.  Runs on the chip only.
 """
 from __future__ import annotations
@@ -35,7 +36,7 @@ def tiny_config():
                        swa_window=0)
 
 
-def record(out: str):
+def record(out: str, codec: str = "none"):
     import jax
     from repro.configs.base import FSLConfig
     from repro.core.bundle import transformer_bundle
@@ -45,14 +46,15 @@ def record(out: str):
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("record_trace: no TPU; the recorded trace has to "
                          "come from the chip")
-    fsl = FSLConfig(num_clients=MIX["clients"], h=MIX["h"], lr=MIX["lr"],
-                    method="cse_fsl", codec=MIX["codec"])
+    mix = dict(MIX, codec=codec)
+    fsl = FSLConfig(num_clients=mix["clients"], h=mix["h"], lr=mix["lr"],
+                    method="cse_fsl", codec=mix["codec"])
     cfg = tiny_config()
     trainer = Trainer(transformer_bundle(cfg), fsl)
     state = trainer.init(0)
     batcher = traffic_mod.PlanBatcher(
-        traffic_mod.Traffic(MIX, cfg.vocab_size, seed=1))
-    r = MIX["rounds_per_chunk"]
+        traffic_mod.Traffic(mix, cfg.vocab_size, seed=1))
+    r = mix["rounds_per_chunk"]
     state, _ = trainer.run_compiled(state, batcher, r, chunk=r, log_every=1)
     trace_dir = tempfile.mkdtemp(prefix="stage-trace-")
     try:
@@ -75,10 +77,11 @@ def record(out: str):
 def main(argv):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("out")
+    ap.add_argument("--codec", default="none")
     args = ap.parse_args(argv)
     sys.path.insert(0, os.path.join(ROOT, "src"))
     sys.path.insert(0, BENCH)
-    record(args.out)
+    record(args.out, args.codec)
 
 
 if __name__ == "__main__":

@@ -137,7 +137,7 @@ def recorded(tmp_path_factory):
     path = tmp_path_factory.mktemp("trace") / "stage_trace.xplane.pb"
     with gzip.open(DATA, "rb") as src, open(path, "wb") as dst:
         shutil.copyfileobj(src, dst)
-    return stages.events(str(path))
+    return devtrace.events(str(path))
 
 
 def test_recorded_trace_reads_the_name_path_of_each_op(recorded):
