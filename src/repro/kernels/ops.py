@@ -17,6 +17,10 @@ losses, and picks MXU-aligned block sizes from the problem shape.
   - ``swa_attention`` : Pallas forward; backward recomputes through the
                         reference (used on the serving path, grad rarely
                         needed).
+  - ``flash_attention``: causal or sliding-window training attention
+                        through JAX's Pallas splash attention (forward,
+                        dQ and dKV kernels), one MQA kernel per KV group,
+                        visiting only the blocks its mask needs.
   - ``grouped_matmul``: the MoE layer's per-expert matmuls over rows
                         sorted by expert; on a TPU JAX's Pallas ``gmm``
                         (megablox), elsewhere ``lax.ragged_dot``.
@@ -24,9 +28,11 @@ losses, and picks MXU-aligned block sizes from the problem shape.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.sharding import AxisType
 
@@ -313,3 +319,85 @@ def grouped_matmul(x, w, sizes):
     with jax.named_scope(GROUPED_MATMUL):
         out = jax.lax.ragged_dot(x, w, sizes, preferred_element_type=x.dtype)
         return _zero_tail(out, sizes)
+
+
+# ---------------------------------------------------------------------------
+# flash_attention
+# ---------------------------------------------------------------------------
+
+# queries and keys of one block of the flash attention kernels (forward,
+# dQ and dKV): a [512, 512] f32 score tile in VMEM
+ATTN_BLOCK = 512
+
+# the ``jax.monitoring`` event each attention call records as it is
+# traced (``repro.telemetry.attention`` tallies it)
+ATTENTION_EVENT = "/repro/attention/traced"
+
+
+def note_attention(path: str, pairs: int = 0, grid_pairs: int = 0) -> None:
+    """Record one traced attention call: its ``path`` ("kernel" or "xla")
+    and, for the kernel, the block pairs it computes of its grid's."""
+    jax.monitoring.record_event(ATTENTION_EVENT, path=path, pairs=pairs,
+                                grid_pairs=grid_pairs)
+
+
+def flash_attention_engages(q, k, *, causal: bool, kv_len, q_offset,
+                            chunk: int) -> bool:
+    """True when ``models.layers.attention`` runs ``flash_attention``:
+    where Mosaic kernels run compiled (``fused_ce_native``: a TPU, no
+    mesh partitioning the program), for causal self-attention from
+    position 0 over every key (no ``kv_len``: decode keeps XLA), whose
+    query axis exceeds one of the XLA path's q-chunks (``chunk``, where
+    that path would scan) and is a whole number of ``ATTN_BLOCK``s."""
+    s = q.shape[1]
+    return (causal and kv_len is None and isinstance(q_offset, int)
+            and q_offset == 0 and k.shape[1] == s and s > chunk
+            and s % ATTN_BLOCK == 0 and fused_ce_native(q))
+
+
+@functools.lru_cache(maxsize=16)
+def _flash_kernel(group: int, s: int, window: int, block: int,
+                  interpret: bool):
+    """The splash attention kernel of one KV group: ``group`` query heads
+    over one K/V head, S = ``s``, causal, masked to the last ``window``
+    keys where nonzero; built once per shape (its block maps are
+    constants, made outside any trace)."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+    mask = (sa.LocalMask((s, s), window_size=(window - 1, 0), offset=0)
+            if window else sa.CausalMask((s, s)))
+    sizes = sa.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    with jax.ensure_compile_time_eval():
+        return sa.make_splash_mqa_single_device(
+            sa.MultiHeadMask([mask] * group), block_sizes=sizes,
+            interpret=interpret)
+
+
+def flash_block_pairs(kernel, s: int, block: int):
+    """(computed, all) (q-block, kv-block) pairs of ``kernel``'s forward
+    grid, read from its mask's block map."""
+    bm = np.asarray(kernel.fwd_mask_info.block_mask)
+    return int(np.count_nonzero(bm)) // bm.shape[0], (s // block) ** 2
+
+
+def flash_attention(q, k, v, window: int = 0, block: int = ATTN_BLOCK):
+    """Causal attention, sliding over the last ``window`` keys where
+    nonzero, through JAX's Pallas splash attention kernels: a flash
+    forward and dQ / dKV backward kernels that skip every block pair the
+    mask leaves empty, so no score tile reaches HBM.  q: [B,S,H,hd];
+    k, v: [B,S,KH,hd] at their own KV heads: one MQA kernel per group of
+    H/KH query heads, vmapped over groups and batch.  q is scaled by
+    1/sqrt(hd) before the kernel (in f32, rounded once to its dtype);
+    Q.K^T takes the operands' dtype with f32 accumulation, the softmax
+    runs in f32."""
+    b, s, h, hd = q.shape
+    kh = k.shape[2]
+    kernel = _flash_kernel(h // kh, s, window, block, _interpret())
+    note_attention("kernel", *flash_block_pairs(kernel, s, block))
+    qs = (q.astype(jnp.float32) * (1.0 / math.sqrt(hd))).astype(q.dtype)
+    qg = qs.reshape(b, s, kh, h // kh, hd).transpose(0, 2, 3, 1, 4)
+    kg, vg = (x.transpose(0, 2, 1, 3) for x in (k, v))   # [B,KH,S,hd]
+    out = jax.vmap(jax.vmap(kernel))(qg, kg, vg)         # [B,KH,H/KH,S,hd]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, hd)

@@ -153,11 +153,20 @@ def attention(q, k, v, *, causal: bool, window: int = 0,
     ``chunk`` queries (fewer where ``SCORES_PER_CHUNK`` asks) by a
     ``lax.scan`` so the score matrix never materializes at [Sq,Skv]; each
     chunk's block is checkpointed, so the backward pass recomputes one
-    chunk's scores at a time instead of keeping every chunk's.
+    chunk's scores at a time instead of keeping every chunk's.  Where
+    ``ops.flash_attention_engages`` (a TPU, no mesh, causal training or
+    prefill longer than one q-chunk) the block-sparse flash kernel
+    ``ops.flash_attention`` runs instead.  Each traced call records its
+    path (``ops.note_attention``).
     """
+    from repro.kernels import ops
     b, sq, h, hd = q.shape
     skv, kh = k.shape[1], k.shape[2]
     chunk = min(chunk, max(128, SCORES_PER_CHUNK // skv))
+    if ops.flash_attention_engages(q, k, causal=causal, kv_len=kv_len,
+                                   q_offset=q_offset, chunk=chunk):
+        return ops.flash_attention(q, k, v, window)
+    ops.note_attention("xla")
     k = _repeat_kv(k, h // kh)
     v = _repeat_kv(v, h // kh)
     scale = 1.0 / math.sqrt(hd)

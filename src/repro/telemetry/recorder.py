@@ -17,7 +17,9 @@ clock (one flag check when none runs).  An enabled recorder labels each
 timed span with ``compiles``, the backend compiles JAX reported during it,
 and keeps the counters ``xla_compiles_total`` and
 ``compile_seconds_total{phase=trace|lower|compile}`` since its creation
-(:mod:`repro.telemetry.compiles`).
+(:mod:`repro.telemetry.compiles`), and beside them
+``attention_traced_total{path=kernel|xla}`` and the gauge
+``attention_block_share`` (:mod:`repro.telemetry.attention`).
 
 The hard contract (rule T001, ``tests/test_telemetry.py``): telemetry is
 **observation-only**.  A disabled recorder is the `NullTelemetry`
@@ -40,7 +42,7 @@ from typing import Any, Dict, List, Mapping, Optional, Tuple
 from jax.profiler import TraceAnnotation
 
 from repro.core.accounting import flat_record
-from repro.telemetry import compiles
+from repro.telemetry import attention, compiles
 from repro.telemetry.record import (make_round_record, make_summary_record,
                                     validate_record)
 
@@ -119,7 +121,9 @@ class Telemetry:
         self.spans: List[Span] = []
         self.records: List[Dict[str, Any]] = []
         compiles.install()
+        attention.install()
         self._compiles0 = compiles.snapshot()
+        self._attention0 = attention.snapshot()
 
     # -- scalars -------------------------------------------------------------
     def counter(self, name: str, value: float = 1, **labels):
@@ -136,14 +140,25 @@ class Telemetry:
         made (process-wide: every thread's programs count)."""
         return compiles.snapshot() - self._compiles0
 
+    def attention_tally(self) -> "attention.Tally":
+        """The attention calls JAX traced since this recorder was made,
+        by path (process-wide, as :meth:`compile_tally`)."""
+        return attention.snapshot() - self._attention0
+
     def note_compiles(self):
         """Bring ``xla_compiles_total`` and ``compile_seconds_total``
-        up to :meth:`compile_tally`."""
+        up to :meth:`compile_tally`, and ``attention_traced_total`` and
+        ``attention_block_share`` up to :meth:`attention_tally`."""
         t = self.compile_tally()
         self.counters[_key("xla_compiles_total", {})] = t.compiles
         for phase in compiles.PHASES:
             self.counters[_key("compile_seconds_total",
                                {"phase": phase})] = t.secs(phase)
+        a = self.attention_tally()
+        for path in ("kernel", "xla"):
+            self.counters[_key("attention_traced_total",
+                               {"path": path})] = getattr(a, path)
+        self.gauges[_key("attention_block_share", {})] = a.block_share
 
     # -- spans ---------------------------------------------------------------
     def sim_span(self, name: str, start: float, dur: float, track: str,
@@ -248,6 +263,9 @@ class NullTelemetry(Telemetry):
 
     def compile_tally(self):
         return compiles.Tally()
+
+    def attention_tally(self):
+        return attention.Tally()
 
     def note_compiles(self):
         pass

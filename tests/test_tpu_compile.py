@@ -179,6 +179,77 @@ def test_grouped_matmul_compiles_vmapped(one_chip, monkeypatch):
     assert "ragged-dot" not in text
 
 
+def _mellum_attention(window, native, one_chip, monkeypatch):
+    """Mellum2's attention sub-block (``blocks.attn_apply``: projections,
+    RoPE, YaRN in the full layer, attention) forward and backward,
+    vmapped over 4 clients at B 2, S 4,096, 32 heads over 4 KV heads of
+    128, compiled with the backend probe on the kernel's branch or on
+    the XLA path's; the compiled program and the attention tally of its
+    trace."""
+    import dataclasses
+    import repro.core  # noqa: F401  (repro.models is imported through it)
+    from repro.configs.registry import get_config
+    from repro.models import blocks
+    from repro.telemetry import attention as tally
+    tally.install()
+    monkeypatch.setattr(ops, "_interpret", lambda: not native)
+    cfg = get_config("mellum2-12b-a2.5b")
+    ctx = blocks.Ctx(cfg, "train")
+    ctx = (dataclasses.replace(ctx, layer_window=window) if window
+           else dataclasses.replace(ctx, yarn=cfg.yarn))
+    n, b, s = 4, 2, 4096
+    params = jax.eval_shape(lambda: jax.vmap(
+        lambda k: blocks.attn_init(cfg, k, jnp.bfloat16))(
+            jax.random.split(jax.random.PRNGKey(0), n)))
+    params = jax.tree_util.tree_map(
+        lambda x: _spec(x.shape, x.dtype, one_chip), params)
+
+    def loss(p, x):
+        y, _ = blocks.attn_apply(cfg, p, x, ctx, None)
+        return jnp.sum(y.astype(jnp.float32))
+
+    before = tally.snapshot()
+    compiled = _compile(jax.vmap(jax.grad(loss, argnums=(0, 1))), params,
+                        _spec((n, b, s, cfg.d_model), jnp.bfloat16,
+                              one_chip))
+    return compiled, tally.snapshot() - before
+
+
+def _name_path_scopes(path):
+    """The components of an op's name path, transformations stripped:
+    ``jit(f)/vmap(transpose(jvp(attention)))/x`` -> {f, attention, x}."""
+    parts = set()
+    for part in path.split("/"):
+        while (m := re.fullmatch(r"[\w.]+\((.*)\)", part)):
+            part = m.group(1)
+        parts.add(part)
+    return parts
+
+
+@pytest.mark.parametrize("window", [1024, 0], ids=["sliding", "full"])
+def test_mellum_attention_compiles_through_the_flash_kernel(
+        one_chip, monkeypatch, window):
+    """On the chip's branch Mellum2's sliding and full attention take the
+    splash kernels (forward, dQ, dKV), with less temp than the XLA q-chunk
+    scan (f32 scores and K/V repeated to 32 heads: 3.79 GiB compiled
+    here, against 1.61 GiB); every kernel op carries the ``attention``
+    scope in its name path, which ``attention_ms`` reads."""
+    xla, xla_tally = _mellum_attention(window, False, one_chip, monkeypatch)
+    kern, kern_tally = _mellum_attention(window, True, one_chip, monkeypatch)
+    assert (xla_tally.kernel, xla_tally.xla) == (0, 1)
+    assert (kern_tally.kernel, kern_tally.xla) == (1, 0)
+    assert kern_tally.pairs == (21 if window else 36)
+    assert "splash" not in xla.as_text()
+    temp = [c.memory_analysis().temp_size_in_bytes for c in (xla, kern)]
+    assert temp[1] < temp[0], f"kernel temp {temp[1]} >= XLA's {temp[0]}"
+    paths = set(re.findall(r'op_name="([^"]*splash_mqa_[^"]*pallas_call)"',
+                           kern.as_text()))
+    for phase in ("fwd", "dq", "dkv"):
+        assert any(f"splash_mqa_{phase}" in p for p in paths), phase
+    for p in paths:
+        assert "attention" in _name_path_scopes(p), p
+
+
 @pytest.mark.parametrize("codec", ["none", "int8"])
 def test_full_width_chunk_program_fits_one_chip(one_chip, monkeypatch,
                                                 codec):
@@ -247,3 +318,38 @@ def test_sharded_cohort_chunk_keeps_the_xla_ce(topo, monkeypatch):
                 for shape in re.findall(r"\[([\d,]+)\]",
                                         line.split("all-gather")[0])}
     assert up not in gathered, f"the stacked aux up [{up}] is all-gathered"
+
+
+def test_sharded_cohort_chunk_keeps_the_xla_attention(topo, monkeypatch):
+    """The ``--mesh host`` cohort chunk program of reduced qwen3-0.6b at
+    S 1,024 (two of the XLA path's q-chunks, where one chip takes the
+    flash kernel), 8 clients split over "data" on four described chips:
+    XLA cannot partition a Mosaic kernel, so attention keeps the XLA path
+    there. It compiles with the backend probe on the chip's branch,
+    holds no splash kernel, and traces no attention call through it."""
+    from repro.configs.base import FSLConfig
+    from repro.configs.registry import get_config
+    from repro.core.bundle import transformer_bundle
+    from repro.core.trainer import Trainer
+    from repro.sharding import state_specs
+    from repro.telemetry import attention as tally
+    tally.install()
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    n, h, b, s, pool = 8, 1, 2, 1024, 64
+    fsl = FSLConfig(num_clients=n, h=h, lr=0.1, method="cse_fsl")
+    tr = Trainer(transformer_bundle(get_config("qwen3-0.6b").reduced()), fsl)
+    abstract = jax.eval_shape(tr.init)
+    state = jax.tree_util.tree_map(
+        lambda x, spec: _spec(x.shape, x.dtype, NamedSharding(mesh, spec)),
+        abstract, state_specs(abstract, mesh=mesh, fsdp_server=False))
+    rep = NamedSharding(mesh, PartitionSpec())
+    tokens = _spec((pool, s), jnp.int32, rep)
+    before = tally.snapshot()
+    text = tr.pool_chunk_fn.lower(
+        state, ({"tokens": tokens}, tokens),
+        _spec((1, n, h, b), jnp.int32, rep),
+        _spec((1,), jnp.float32, rep)).compile().as_text()
+    counted = tally.snapshot() - before
+    assert counted.kernel == 0 and counted.xla > 0
+    assert "splash" not in text
